@@ -135,6 +135,9 @@ def main(argv: "list[str] | None" = None) -> None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={mesh_n}"
         ).strip()
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         io_stats,
         join_rates,

@@ -25,7 +25,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "experiments/xla_cache")
+from repro.launch import compile_cache
+
+compile_cache.enable()
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 

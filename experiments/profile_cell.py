@@ -9,11 +9,12 @@ import sys
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
+import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "experiments/xla_cache")
+from repro.launch import compile_cache, hlo_analysis
 
-from repro.launch import hlo_analysis
+compile_cache.enable()
+
 from repro.launch.dryrun import run_cell  # noqa: F401  (reuses builders)
 
 

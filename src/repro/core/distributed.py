@@ -26,7 +26,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import learned_sort, partition, rmi
 from repro.core.encoding import SENTINEL
@@ -120,12 +119,12 @@ def make_sort_fn(
         return hi_s, lo_s, val_s, n_valid[None], lost[None]
 
     spec = P(axis_names)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec, spec, spec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -15,11 +15,11 @@ offsets).  Three implementations:
   (``executor="per_partition"``).
 * :class:`BatchedDeviceExecutor` — the default device executor: packs
   partitions into fixed-shape super-batches with segment ids and runs
-  ``kernels/fused.fused_segmented_sort`` — encode happens **on device**
-  (the Pallas encode kernel), and one dispatch covers up to
-  ``max_segments`` partitions.  Dispatches are **double-buffered**: while
-  batch *k* computes, batch *k+1* is packed and dispatched and batch
-  *k−1*'s permutation is fetched, so H2D, compute, and D2H overlap.
+  one ``kernels/fused`` sort graph per batch — encode happens **on
+  device**, and one dispatch covers up to ``max_segments`` partitions.
+  Dispatches are **double-buffered**: while batch *k* computes, batch
+  *k+1* is packed and dispatched and batch *k−1*'s permutation is
+  fetched, so H2D, compute, and D2H overlap.
 
 Every executor produces output byte-identical to the host path: the
 stable memcmp order of the full key window, with the GNU-``strncmp``
@@ -212,13 +212,16 @@ class BatchedDeviceExecutor(SortExecutor):
 
     Two dispatch shapes behind the same packing/epilogue protocol:
 
-    * **flat** (default on CPU backends without ``use_kernels``): one
-      stable ``lax.sort`` over ``(seg, hi, lo)`` with pure-jnp encode —
-      the grid path's overflow fallback promoted to the primary, which
-      on CPU both runs and compiles several times faster than the
-      scatter-grid graph (whose Pallas kernels run in interpret mode).
-    * **grid** (accelerators / ``use_kernels``): Pallas encode → fused
-      RMI → per-segment affine remap → segmented bitonic
+    * **flat** (the default on every backend): one stable ``lax.sort``
+      over ``(seg, hi, lo)`` with pure-jnp encode — the grid path's
+      overflow fallback promoted to the primary.  On CPU it runs and
+      compiles several times faster than the scatter-grid graph (whose
+      Pallas kernels run in interpret mode).  On TPU the grid graph
+      holds this same full-size sort as its overflow branch plus two
+      more (the bucket grouping and the per-row sort), so it can only
+      compile slower: 245 s against 106 s at 2**20 rows for a v5e.
+    * **grid** (``use_kernels``): Pallas encode → fused RMI →
+      per-segment affine remap → segmented bitonic
       (``kernels/fused.fused_segmented_sort``).
 
     Both pack into size-bucketed static shapes (``fused.pad_target``:
@@ -253,9 +256,9 @@ class BatchedDeviceExecutor(SortExecutor):
         from repro.kernels import fused
 
         on_cpu = jax.default_backend() == "cpu"
-        # flat=None -> auto: the comparison sort wins on CPU; the grid
-        # graph wins where the Pallas kernels actually compile
-        self.flat = (on_cpu and not use_kernels) if flat is None else flat
+        # flat=None -> auto: the flat graph unless the kernels are asked
+        # for (see the class docstring for why TPU takes it too)
+        self.flat = (not use_kernels) if flat is None else flat
         if not self.flat:
             # one-time host->device upload; dispatches reuse the leaves
             self.model = rmi.device_params(model)
@@ -470,31 +473,25 @@ class MeshBatchedExecutor(SortExecutor):
         fn = self._fns.get(n_pad)
         if fn is None:
             import jax
-            import jax.numpy as jnp
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
-            from repro.core import encoding
+            from repro.kernels import fused
 
             def local_fn(keys, seg):
                 # local shapes: keys (1, n_pad, 8), seg (1, n_pad)
-                hi, lo = encoding.encode(keys.reshape(n_pad, -1))
-                idx = jnp.arange(n_pad, dtype=jnp.int32)
-                _, _, _, perm = jax.lax.sort(
-                    (seg.reshape(n_pad), hi, lo, idx),
-                    num_keys=3,
-                    is_stable=True,
+                perm = fused._flat_impl(
+                    keys.reshape(n_pad, -1), seg.reshape(n_pad)
                 )
                 return perm.reshape(1, n_pad)
 
             spec = P(self.axis_names)
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     local_fn,
                     mesh=self.mesh,
                     in_specs=(spec, spec),
                     out_specs=spec,
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
             self._fns[n_pad] = fn
@@ -504,7 +501,6 @@ class MeshBatchedExecutor(SortExecutor):
 
     def _dispatch(self, entries: list) -> tuple:
         import jax
-        import jax.numpy as jnp
 
         from repro.kernels import fused
 
@@ -533,9 +529,11 @@ class MeshBatchedExecutor(SortExecutor):
         self._count_dispatch(
             self.n_dev * n_pad, total, ("mesh", self.n_dev, n_pad)
         )
+        # straight from NumPy: each device receives only its own shard
+        # (a jnp.asarray first would stage the whole batch on device 0)
         perm_dev = self._sort_fn(n_pad)(
-            jax.device_put(jnp.asarray(keys), self._sharding),
-            jax.device_put(jnp.asarray(seg), self._sharding),
+            jax.device_put(keys, self._sharding),
+            jax.device_put(seg, self._sharding),
         )
         return dev_entries, perm_dev
 

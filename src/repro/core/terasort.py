@@ -154,11 +154,8 @@ def sort_file_distributed(
                     hi = np.concatenate([hi, fill])
                     lo = np.concatenate([lo, fill])
                 val = np.arange(m + pad, dtype=np.int32)
-                args = (
-                    jax.device_put(jnp.asarray(hi), sh),
-                    jax.device_put(jnp.asarray(lo), sh),
-                    jax.device_put(jnp.asarray(val), sh),
-                )
+                # straight from NumPy: no chunk is staged on device 0
+                args = tuple(jax.device_put(a, sh) for a in (hi, lo, val))
                 # graceful degradation: rare pathological chunks re-run
                 # with a doubled capacity (lossless — overflow is always
                 # detected before anything is dropped)
@@ -293,8 +290,6 @@ def _make_route_fn(mesh, axis_names, model, n_per_device, capacity_factor):
     (``val``) cross the wire; keys are used locally for bucketing and
     dropped.  Returns ``fn(hi, lo, val) -> (val_routed, n_valid, lost)``
     with ``val_routed`` per-device arrival-compacted row indices."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.core import partition
     from repro.core.encoding import SENTINEL
 
@@ -338,11 +333,11 @@ def _make_route_fn(mesh, axis_names, model, n_per_device, capacity_factor):
         return jnp.take(recv_val, order), n_valid[None], lost[None]
 
     spec = P(axis_names)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)

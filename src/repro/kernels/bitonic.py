@@ -3,8 +3,9 @@
 This is ELSAR's *touch-up* sorter, TPU-adapted (DESIGN.md §2): the paper
 uses InsertionSort for last-mile fixing — a sequential, branchy CPU idiom.
 The branch-free equivalent with the same role on a vector unit is a bitonic
-network: every compare-exchange stage is a static permutation + select,
-which maps onto the 8x128 VPU lanes with no data-dependent control flow.
+network: every compare-exchange stage is two static lane rotations + a
+select, which maps onto the 8x128 VPU lanes with no data-dependent
+control flow.
 
 Each grid step sorts ``block_rows`` independent rows of width C (a power of
 two) entirely in VMEM.  Keys are 64-bit ``(hi, lo)`` word pairs compared
@@ -22,6 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _stage_list(c: int):
@@ -37,12 +39,14 @@ def _stage_list(c: int):
     return stages
 
 
-def _partner_swap(x: jnp.ndarray, j: int) -> jnp.ndarray:
-    """x[:, idx ^ j] as a pure reshape+flip (no gather): XOR with j swaps
-    adjacent j-sized blocks, which vectorizes on the VPU."""
-    r, c = x.shape
-    xr = x.reshape(r, c // (2 * j), 2, j)
-    return jnp.flip(xr, axis=2).reshape(r, c)
+def _partner_swap(x: jnp.ndarray, j: int, is_lower: jnp.ndarray) -> jnp.ndarray:
+    """x[:, idx ^ j] without a gather: the lower slot of each pair reads
+    j lanes ahead and the upper slot j lanes behind — two lane rotations
+    and a select (Mosaic lowers no lane reversal, so no ``jnp.flip``)."""
+    c = x.shape[1]
+    ahead = pltpu.roll(x, c - j, 1)  # ahead[:, i] = x[:, (i + j) % c]
+    behind = pltpu.roll(x, j, 1)  # behind[:, i] = x[:, (i - j) % c]
+    return jnp.where(is_lower, ahead, behind)
 
 
 def _make_kernel(c: int):
@@ -52,16 +56,16 @@ def _make_kernel(c: int):
         hi = hi_ref[...]
         lo = lo_ref[...]
         val = val_ref[...]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+        idx = jax.lax.broadcasted_iota(jnp.int32, hi.shape, 1)
         for k, j in stages:
             # masks derived from iota with static k, j (no captured consts)
             is_lower = (idx & j) == 0  # idx < (idx ^ j)
             up = (idx & k) == 0
             # position holds the MIN of the pair iff (lower XNOR ascending)
             want_min = is_lower == up
-            hi_p = _partner_swap(hi, j)
-            lo_p = _partner_swap(lo, j)
-            val_p = _partner_swap(val, j)
+            hi_p = _partner_swap(hi, j, is_lower)
+            lo_p = _partner_swap(lo, j, is_lower)
+            val_p = _partner_swap(val, j, is_lower)
             # Strict total order (val tiebreak) so that duplicate keys can
             # never be kept/taken by BOTH slots of a pair (which would
             # duplicate one payload and drop the other).
@@ -71,8 +75,9 @@ def _make_kernel(c: int):
                 | ((hi == hi_p) & (lo == lo_p) & (val > val_p))
             )
             # want_min slot: take partner when self > partner (strict)
-            # want_max slot: take partner when self < partner
-            take_p = jnp.where(want_min, gt, ~gt)
+            # want_max slot: take partner when self < partner.  Plain
+            # and/or: Mosaic refuses a select between two bool arrays.
+            take_p = (want_min & gt) | ~(want_min | gt)
             hi = jnp.where(take_p, hi_p, hi)
             lo = jnp.where(take_p, lo_p, lo)
             val = jnp.where(take_p, val_p, val)

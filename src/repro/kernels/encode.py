@@ -1,14 +1,20 @@
 """Pallas TPU kernel: ASCII key bytes -> (hi, lo) uint32 embedding.
 
 This is the front of the paper's hot loop (encode -> RMI -> scatter,
-23.5% of ELSAR's runtime, Fig. 6).  Row-tiled: each grid step loads a
-``(block_rows, 8)`` u8 tile of key bytes into VMEM and emits two
+23.5% of ELSAR's runtime, Fig. 6).  Row-tiled: each grid step loads an
+``(8, block_rows)`` u8 tile of key bytes into VMEM and emits two
 ``(block_rows,)`` u32 words.
 
-VMEM budget per step: 8*block_rows bytes in + 8*block_rows out — with the
-default block_rows=1024 that is 16 KiB, far under the ~16 MiB VMEM of a
-TPU v5e core; the tile is deliberately small so several grid steps can be
-double-buffered by the Pallas pipeline.
+The keys arrive byte-major — ``(8, N)``, one XLA transpose of the
+``(N, 8)`` key matrix — so each key byte position is a lane-dense row of
+the tile.  The key-major layout needed column slices of an ``(R, 8)``
+tile, which Mosaic compiles but gets wrong on a v5e (byte 1 of each word
+read back as 0).
+
+VMEM budget per step: 8*block_rows bytes in (padded to 32 sublanes:
+32 KiB at the default block_rows=1024) + 8*block_rows out — far under
+the ~16 MiB VMEM of a TPU v5e core; the tile is deliberately small so
+several grid steps can be double-buffered by the Pallas pipeline.
 """
 
 from __future__ import annotations
@@ -21,9 +27,9 @@ from repro.core.encoding import ENCODED_BYTES
 
 
 def _encode_kernel(keys_ref, hi_ref, lo_ref):
-    k = keys_ref[...].astype(jnp.uint32)  # (R, 8)
-    hi_ref[...] = (k[:, 0] << 24) | (k[:, 1] << 16) | (k[:, 2] << 8) | k[:, 3]
-    lo_ref[...] = (k[:, 4] << 24) | (k[:, 5] << 16) | (k[:, 6] << 8) | k[:, 7]
+    k = keys_ref[...].astype(jnp.uint32)  # (8, R): one key byte per row
+    hi_ref[...] = (k[0] << 24) | (k[1] << 16) | (k[2] << 8) | k[3]
+    lo_ref[...] = (k[4] << 24) | (k[5] << 16) | (k[6] << 8) | k[7]
 
 
 def encode_pallas(
@@ -37,7 +43,7 @@ def encode_pallas(
     return pl.pallas_call(
         _encode_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, ENCODED_BYTES), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((ENCODED_BYTES, block_rows), lambda i: (0, i))],
         out_specs=[
             pl.BlockSpec((block_rows,), lambda i: (i,)),
             pl.BlockSpec((block_rows,), lambda i: (i,)),
@@ -47,4 +53,4 @@ def encode_pallas(
             jax.ShapeDtypeStruct((n,), jnp.uint32),
         ],
         interpret=interpret,
-    )(keys)
+    )(keys.T)

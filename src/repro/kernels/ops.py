@@ -1,15 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the TPU target the kernels run compiled; on this CPU container they run
-in ``interpret=True`` mode (the kernel body executed per-block in Python),
-which is how they are validated against ref.py.  Set
-``REPRO_FORCE_PALLAS_COMPILED=1`` to force compiled mode (TPU hosts).
+The backend alone decides the mode: on a TPU the kernels run compiled;
+on the CPU backend they run in ``interpret=True`` mode (the kernel body
+executed per block by XLA), which is how the tests check them against
+ref.py.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +19,6 @@ from repro.kernels import bitonic, encode, histogram, rmi
 
 
 def _interpret() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS_COMPILED"):
-        return False
     return jax.default_backend() == "cpu"
 
 
@@ -132,11 +129,10 @@ def rmi_predict_pos(
 
 @functools.partial(jax.jit, static_argnames=("n_buckets", "block_rows"))
 def bucket_histogram(
-    bucket_ids: jnp.ndarray, n_buckets: int, *, block_rows: int = 512
+    bucket_ids: jnp.ndarray, n_buckets: int, *, block_rows: int = 1024
 ) -> jnp.ndarray:
-    # keep the one-hot tile under ~8 MiB of VMEM
-    while block_rows * n_buckets * 4 > 8 * 1024 * 1024 and block_rows > 8:
-        block_rows //= 2
+    # the kernel chunks the bucket axis, so the one-hot tile stays small
+    # at any n_buckets without shrinking block_rows below the 1024 tile
     ids, _ = _pad_rows(bucket_ids, block_rows, -1)  # -1 never matches a bucket
     return histogram.histogram_pallas(
         ids, n_buckets, block_rows=block_rows, interpret=_interpret()

@@ -1,15 +1,25 @@
-"""Pallas TPU kernel: fused 2-level RMI CDF inference + bucket id.
+"""Pallas TPU kernels: 2-level RMI CDF inference + bucket id.
 
-Fuses the global (routing) feature, the root linear model, the leaf gather,
-the leaf-local feature reconstruction (per-leaf integer offset + scale —
-the hierarchical-precision scheme of core/rmi.py), the leaf FMA and the
-band clamp into one VMEM-resident pass — the paper's per-record prediction
-hot path (§3.3).
+The paper's per-record prediction hot path (§3.3) in two VMEM passes:
 
-Both leaf tables are pinned whole into VMEM (index_map -> block (0, 0)):
-``(L, 5) f32`` + ``(L, 2) u32`` = 28 KiB at the default L=1024.  Per grid
-step: block_rows * 8 B of key words + tables + block_rows * 4 B out
-≈ 44 KiB VMEM at block_rows=1024 — small enough for deep double-buffering.
+1. **route** — the global (routing) feature and the root linear model,
+   emitting each key's leaf id;
+2. **leaf** — the leaf-local feature reconstruction (per-leaf integer
+   offset + scale — the hierarchical-precision scheme of core/rmi.py),
+   the leaf FMA, the band clamp and the bucket id.
+
+Between them the leaf rows are gathered by XLA: Mosaic has no vector
+gather from a VMEM table (it refuses ``jnp.take`` on the ``(L, 5)`` leaf
+table), and an XLA gather of seven words per key is cheap next to the
+two elementwise passes.  The gathered rows arrive column-major, ``(5, N)
+f32`` + ``(2, N) u32``, so every block keeps the key axis on the lanes.
+
+Mosaic has no unsigned->float cast either; :func:`_u32_to_f32` converts
+through two exact 16-bit halves, so the result is the correctly rounded
+f32 — bit-identical to XLA's ``astype`` in the reference.
+
+VMEM per grid step at block_rows=1024: 8 KiB of key words, 28 KiB of
+gathered leaf rows (padded to 8 sublanes: 64 KiB) and 4 KiB out.
 """
 
 from __future__ import annotations
@@ -19,43 +29,51 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _u32_to_f32(x):
+    """Correctly rounded uint32 -> float32 without an unsigned cast: both
+    16-bit halves are exact in f32, so the sum rounds exactly once."""
+    hi = (x >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (x & 0xFFFF).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
+
+
 def _feature(hi, lo, min_hi, min_lo, inv_range):
     below = (hi < min_hi) | ((hi == min_hi) & (lo < min_lo))
     borrow = (lo < min_lo).astype(jnp.uint32)
     dlo = lo - min_lo
     dhi = hi - min_hi - borrow
-    x = dhi.astype(jnp.float32) * jnp.float32(4294967296.0) + dlo.astype(
-        jnp.float32
-    )
+    x = _u32_to_f32(dhi) * jnp.float32(4294967296.0) + _u32_to_f32(dlo)
     return jnp.where(below, 0.0, jnp.clip(x * inv_range, 0.0, 1.0))
 
 
-def _rmi_kernel(hi_ref, lo_ref, ints_ref, consts_ref, ft_ref, ut_ref, bucket_ref):
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    min_hi = ints_ref[0]
-    min_lo = ints_ref[1]
-    inv_range = consts_ref[0]
-    root_slope = consts_ref[1]
-    root_intercept = consts_ref[2]
-    n_buckets = consts_ref[3]
-    ftable = ft_ref[...]  # (L, 5): slope, icept, band_lo, band_hi, inv_range
-    utable = ut_ref[...]  # (L, 2): leaf_min_hi, leaf_min_lo
-    n_leaf = ftable.shape[0]
-
+def _route_kernel(n_leaf, hi_ref, lo_ref, ints_ref, consts_ref, leaf_ref):
     # root routing on the coarse global feature
-    x = _feature(hi, lo, min_hi, min_lo, inv_range)
-    leaf = jnp.clip(
-        ((x * root_slope + root_intercept) * n_leaf).astype(jnp.int32),
+    x = _feature(
+        hi_ref[...], lo_ref[...], ints_ref[0], ints_ref[1], consts_ref[0]
+    )
+    leaf_ref[...] = jnp.clip(
+        ((x * consts_ref[1] + consts_ref[2]) * n_leaf).astype(jnp.int32),
         0,
         n_leaf - 1,
     )
-    frow = jnp.take(ftable, leaf, axis=0)  # (R, 5)
-    urow = jnp.take(utable, leaf, axis=0)  # (R, 2)
 
+
+def _leaf_kernel(
+    hi_ref, lo_ref, consts_ref,
+    slope_ref, icept_ref, band_lo_ref, band_hi_ref, inv_ref,
+    min_hi_ref, min_lo_ref,
+    bucket_ref,
+):
+    n_buckets = consts_ref[3]
     # leaf-local feature (full f32 precision inside the leaf's key span)
-    xl = _feature(hi, lo, urow[:, 0], urow[:, 1], frow[:, 4])
-    y = jnp.clip(xl * frow[:, 0] + frow[:, 1], frow[:, 2], frow[:, 3])
+    xl = _feature(
+        hi_ref[...], lo_ref[...], min_hi_ref[...], min_lo_ref[...],
+        inv_ref[...],
+    )
+    y = jnp.clip(
+        xl * slope_ref[...] + icept_ref[...], band_lo_ref[...],
+        band_hi_ref[...],
+    )
     bucket_ref[...] = jnp.minimum(
         (y * n_buckets).astype(jnp.int32), n_buckets.astype(jnp.int32) - 1
     )
@@ -76,18 +94,26 @@ def rmi_bucket_pallas(
     assert n % block_rows == 0, (n, block_rows)
     n_leaf = ftable.shape[0]
     grid = (n // block_rows,)
-    return pl.pallas_call(
-        _rmi_kernel,
+    rows = pl.BlockSpec((block_rows,), lambda i: (i,))
+    ints_spec = pl.BlockSpec((2,), lambda i: (0,))
+    consts_spec = pl.BlockSpec((4,), lambda i: (0,))
+    leaf = pl.pallas_call(
+        lambda *refs: _route_kernel(n_leaf, *refs),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((block_rows,), lambda i: (i,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-            pl.BlockSpec((n_leaf, 5), lambda i: (0, 0)),
-            pl.BlockSpec((n_leaf, 2), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_rows,), lambda i: (i,)),
+        in_specs=[rows, rows, ints_spec, consts_spec],
+        out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
         interpret=interpret,
-    )(hi, lo, ints, consts, ftable, utable)
+    )(hi, lo, ints, consts)
+    # one 1-D gather per leaf column: a gather of (L, 5) rows would land
+    # as an (n, 5) array padded to 128 lanes (25x its size in HBM)
+    cols = [jnp.take(ftable[:, c], leaf) for c in range(5)]
+    cols += [jnp.take(utable[:, c], leaf) for c in range(2)]
+    return pl.pallas_call(
+        _leaf_kernel,
+        grid=grid,
+        in_specs=[rows, rows, consts_spec] + [rows] * len(cols),
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        interpret=interpret,
+    )(hi, lo, consts, *cols)

@@ -21,11 +21,11 @@ import traceback
 
 import jax
 
-# persistent compilation cache: re-runs of unchanged cells are ~free
-jax.config.update("jax_compilation_cache_dir", "experiments/xla_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+from repro.launch import compile_cache, hlo_analysis
 
-from repro.launch import hlo_analysis
+# persistent compilation cache: re-runs of unchanged cells are ~free
+compile_cache.enable()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
 
 from repro.configs import registry
 from repro.configs.base import shape_applicable

@@ -3,11 +3,6 @@
 A FUNCTION, not a module-level constant: importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS before any jax
 initialization).
-
-``jax.sharding.AxisType`` (and the ``axis_types`` kwarg of
-``jax.make_mesh``) only exist from jax 0.6; older installs (this
-container ships 0.4.x) build the same mesh without explicit axis types,
-which is equivalent to the ``Auto`` default we request on new jax.
 """
 
 from __future__ import annotations
@@ -15,24 +10,20 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,) * n_axes`` when this jax supports it, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod (v5e); 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Elastic variant for tests / reduced topologies."""
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_data_mesh(n_dev: int | None = None):
@@ -40,9 +31,9 @@ def make_data_mesh(n_dev: int | None = None):
     (every visible device by default) — the topology the distributed
     sorter and the mesh executor assume.
 
-    Uses the raw ``Mesh`` constructor rather than ``jax.make_mesh`` so a
-    subset mesh (``n_dev`` < device count) works uniformly across jax
-    versions.
+    Uses the raw ``Mesh`` constructor rather than ``jax.make_mesh``, which
+    always spans every device, so a subset mesh (``n_dev`` < device
+    count) works.
     """
     import numpy as np
 

@@ -38,6 +38,7 @@ import tempfile
 from repro.core import operators
 from repro.core.config import add_sort_cli_args, sort_config_from_args
 from repro.core.format import LineFormat
+from repro.launch import compile_cache
 
 
 def _add_common(ap: argparse.ArgumentParser) -> None:
@@ -125,6 +126,7 @@ def main(argv: "list[str] | None" = None) -> None:
     _add_common(g)
 
     args = ap.parse_args(argv)
+    compile_cache.enable()
     budget = args.budget_mb << 20
 
     if args.op == "join":

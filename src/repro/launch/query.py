@@ -28,6 +28,7 @@ import numpy as np
 from repro.core import external
 from repro.core.config import add_sort_cli_args, sort_config_from_args
 from repro.data import gensort
+from repro.launch import compile_cache
 from repro.serve.index import SortedFileIndex
 from repro.serve.query_engine import QueryEngine
 
@@ -88,6 +89,7 @@ def main(argv: "list[str] | None" = None) -> None:
                     help="predict through the fused Pallas RMI kernel")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.attach:
         index = SortedFileIndex.open(args.attach)

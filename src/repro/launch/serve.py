@@ -45,6 +45,7 @@ from repro.core.config import (
     sort_config_from_args,
 )
 from repro.data import gensort
+from repro.launch import compile_cache
 from repro.serve.index import SortedFileIndex
 from repro.serve.router import ShardRouter
 from repro.serve.server import QueryServer
@@ -113,6 +114,7 @@ def main(argv: "list[str] | None" = None) -> None:
     add_sort_cli_args(ap)
     add_serve_cli_args(ap)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     asyncio.run(_run(args))
 
 

@@ -247,7 +247,6 @@ def _cache_update(cache, k_new, v_new, pos):
     GSPMD cannot "simplify" away: each seq shard checks whether ``pos``
     falls in its range and applies a local DUS or a no-op.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding import rules
@@ -285,12 +284,12 @@ def _cache_update(cache, k_new, v_new, pos):
                 jnp.where(in_range, vu, cv),
             )
 
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(cspec, cspec, nspec, nspec, P()),
             out_specs=(cspec, cspec),
-            check_rep=False,
+            check_vma=False,
         )(cache["k"], cache["v"], k_new, v_new, pos)
 
     k = jax.lax.dynamic_update_slice(
