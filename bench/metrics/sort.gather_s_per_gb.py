@@ -1,0 +1,15 @@
+"""Sort executor (``core/executor``), its gather: each partition's
+records taken in sorted order (span ``sort.gather``), busy seconds per
+input GB summed over the window's jobs (``SortStats.span_seconds``)."""
+
+SPAN = "sort.gather"
+
+
+def read(run):
+    jobs = run.layer.get("jobs")
+    if not jobs or run.layer["input_gb"] <= 0:
+        return None
+    spans = [getattr(s, "span_seconds", None) for s in jobs]
+    if None in spans:  # a program without spans inside the sort
+        return None
+    return sum(sp.get(SPAN, 0.0) for sp in spans) / run.layer["input_gb"]

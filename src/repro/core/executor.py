@@ -28,7 +28,12 @@ touch-up beyond byte 8 applied in the executor's epilogue.
 All executors record ``device_dispatches`` / ``batch_slots`` /
 ``batch_records`` / ``jit_compiles`` counters (on themselves and, when a
 :class:`~repro.core.stages.stats.PhaseClock` is attached, on the clock so
-``SortStats`` picks them up).
+``SortStats`` picks them up).  The two batching executors also split
+their ``sort`` phase into spans on that clock: ``sort.pack`` (host
+packing, H2D copy and launch), ``sort.wait`` (the blocking fetch of the
+permutation), ``sort.touchup``, ``sort.gather``, and ``sort.handoff``
+(the generator suspended at its ``yield`` while the sorter stage hands
+the block to the writers: backpressure, not sort work).
 """
 
 from __future__ import annotations
@@ -88,6 +93,11 @@ class SortExecutor:
         if self.clock is None:
             return contextlib.nullcontext()
         return self.clock.timer(phase)
+
+    def _span(self, name: str):
+        if self.clock is None:
+            return contextlib.nullcontext()
+        return self.clock.span(name)
 
     def _count_dispatch(self, slots: int, records: int, key) -> None:
         self.dispatches += 1
@@ -205,7 +215,67 @@ class PerPartitionDeviceExecutor(SortExecutor):
             yield tag, block
 
 
-class BatchedDeviceExecutor(SortExecutor):
+class _SuperBatchExecutor(SortExecutor):
+    """Stream protocol of the batching executors: partitions collect into
+    a super-batch until ``_max_entries`` segments or the slot/byte caps
+    are reached, then the subclass's ``_dispatch(entries) -> handle``
+    packs and launches it; up to ``depth`` batches are in flight before
+    the oldest is fetched and emitted by its ``_finish(handle)``."""
+
+    parallel_safe = False  # one packer must own the super-batch
+
+    def _launch(self, entries: list, pending: deque) -> None:
+        with self._timer(), self._span("sort.pack"):
+            pending.append(self._dispatch(entries))
+
+    def _fetch(self, perm_dev, overflow_dev=None) -> np.ndarray:
+        """The batch's permutation on the host (blocks until the device
+        is done); counts an overflow fallback."""
+        with self._span("sort.wait"):
+            perm = np.asarray(perm_dev)
+            if overflow_dev is not None and bool(np.asarray(overflow_dev)):
+                self.fallbacks += 1
+        return perm
+
+    def _emit(self, tag, block: RecordBlock, local: np.ndarray):
+        """Touch up and gather one segment, then hand it downstream."""
+        with self._span("sort.touchup"):
+            local = _memcmp_touchup(block.keys, local)
+        with self._span("sort.gather"):
+            block = block.take(local)
+        with self._span("sort.handoff"):
+            yield tag, block
+
+    def sort_iter(self, items):
+        pending: deque = deque()
+        cur: list = []
+        cur_records = 0
+        cur_bytes = 0
+        for tag, block in items:
+            if block.n_records <= 1:
+                yield tag, block  # empty/single: never dispatched
+                continue
+            cur.append((tag, block))
+            cur_records += block.n_records
+            cur_bytes += block.n_bytes
+            if (
+                len(cur) >= self._max_entries
+                or cur_records >= self._slots_cap
+                or cur_bytes >= self._bytes_cap
+            ):
+                self._launch(cur, pending)
+                cur, cur_records, cur_bytes = [], 0, 0
+                while len(pending) >= self.depth:
+                    with self._timer():
+                        yield from self._finish(pending.popleft())
+        if cur:
+            self._launch(cur, pending)
+        while pending:
+            with self._timer():
+                yield from self._finish(pending.popleft())
+
+
+class BatchedDeviceExecutor(_SuperBatchExecutor):
     """Device-resident batched executor: super-batch packing + one fused
     sort dispatch per batch, double-buffered across ``PIPELINE_DEPTH``
     in-flight dispatches (DESIGN.md §10, §12).
@@ -229,7 +299,6 @@ class BatchedDeviceExecutor(SortExecutor):
     pow2) so one dispatch is also the fastest dispatch."""
 
     name = "batched"
-    parallel_safe = False  # one packer must own the super-batch
 
     def __init__(
         self,
@@ -250,6 +319,7 @@ class BatchedDeviceExecutor(SortExecutor):
         self._slots_cap = max(2, batch_slots)
         self._bytes_cap = max(1, batch_bytes)
         self.max_segments = max(1, min(max_segments, MAX_SEGMENTS))
+        self._max_entries = self.max_segments
         self.depth = max(1, depth)
         import jax
 
@@ -358,9 +428,7 @@ class BatchedDeviceExecutor(SortExecutor):
     def _finish(self, handle: tuple):
         """Fetch one batch's permutation and emit its sorted blocks."""
         entries, sizes, total, perm_dev, overflow_dev = handle
-        perm = np.asarray(perm_dev)  # blocks until the device is done
-        if overflow_dev is not None and bool(np.asarray(overflow_dev)):
-            self.fallbacks += 1
+        perm = self._fetch(perm_dev, overflow_dev)
         perm = perm[perm < total]  # drop the padding records
         bases = np.concatenate([[0], np.cumsum(sizes)])
         pos = 0
@@ -373,43 +441,10 @@ class BatchedDeviceExecutor(SortExecutor):
                     f"segmented sort mixed segments: segment {s} got "
                     f"indices outside [0, {m}) — executor invariant broken"
                 )
-            local = _memcmp_touchup(block.keys, local)
-            yield tag, block.take(local)
-
-    # -- stream protocol ----------------------------------------------
-
-    def sort_iter(self, items):
-        pending: deque = deque()
-        cur: list = []
-        cur_records = 0
-        cur_bytes = 0
-        for tag, block in items:
-            if block.n_records <= 1:
-                yield tag, block  # empty/single: never dispatched
-                continue
-            cur.append((tag, block))
-            cur_records += block.n_records
-            cur_bytes += block.n_bytes
-            if (
-                len(cur) >= self.max_segments
-                or cur_records >= self._slots_cap
-                or cur_bytes >= self._bytes_cap
-            ):
-                with self._timer():
-                    pending.append(self._dispatch(cur))
-                cur, cur_records, cur_bytes = [], 0, 0
-                while len(pending) >= self.depth:
-                    with self._timer():
-                        yield from self._finish(pending.popleft())
-        if cur:
-            with self._timer():
-                pending.append(self._dispatch(cur))
-        while pending:
-            with self._timer():
-                yield from self._finish(pending.popleft())
+            yield from self._emit(tag, block, local)
 
 
-class MeshBatchedExecutor(SortExecutor):
+class MeshBatchedExecutor(_SuperBatchExecutor):
     """Mesh-sharded batched executor: the flat super-batch graph run
     *per device inside one ``shard_map`` program* (DESIGN.md §13).
 
@@ -434,7 +469,6 @@ class MeshBatchedExecutor(SortExecutor):
     """
 
     name = "mesh"
-    parallel_safe = False  # one packer owns the super-batch
 
     def __init__(
         self,
@@ -465,6 +499,7 @@ class MeshBatchedExecutor(SortExecutor):
         self._slots_cap = max(2, batch_slots)
         self._bytes_cap = max(1, batch_bytes)
         self.max_segments = max(1, min(max_segments, MAX_SEGMENTS))
+        self._max_entries = self.n_dev * self.max_segments
         self.depth = max(1, depth)
         self._sharding = NamedSharding(mesh, PartitionSpec(self.axis_names))
         self._fns: dict = {}  # n_pad -> jitted shard_map sort
@@ -539,7 +574,7 @@ class MeshBatchedExecutor(SortExecutor):
 
     def _finish(self, handle: tuple):
         dev_entries, perm_dev = handle
-        perm = np.asarray(perm_dev)  # blocks until every device is done
+        perm = self._fetch(perm_dev)
         for d, entries in enumerate(dev_entries):
             sizes = [b.n_records for _, b in entries]
             local_total = sum(sizes)
@@ -561,40 +596,7 @@ class MeshBatchedExecutor(SortExecutor):
                         f"segment {s} got indices outside [0, {m}) — "
                         "executor invariant broken"
                     )
-                local = _memcmp_touchup(block.keys, local)
-                yield tag, block.take(local)
-
-    # -- stream protocol ----------------------------------------------
-
-    def sort_iter(self, items):
-        pending: deque = deque()
-        cur: list = []
-        cur_records = 0
-        cur_bytes = 0
-        for tag, block in items:
-            if block.n_records <= 1:
-                yield tag, block
-                continue
-            cur.append((tag, block))
-            cur_records += block.n_records
-            cur_bytes += block.n_bytes
-            if (
-                len(cur) >= self.n_dev * self.max_segments
-                or cur_records >= self._slots_cap
-                or cur_bytes >= self._bytes_cap
-            ):
-                with self._timer():
-                    pending.append(self._dispatch(cur))
-                cur, cur_records, cur_bytes = [], 0, 0
-                while len(pending) >= self.depth:
-                    with self._timer():
-                        yield from self._finish(pending.popleft())
-        if cur:
-            with self._timer():
-                pending.append(self._dispatch(cur))
-        while pending:
-            with self._timer():
-                yield from self._finish(pending.popleft())
+                yield from self._emit(tag, block, local)
 
 
 def make_executor(

@@ -16,10 +16,11 @@ paper's control flow:
               run as queue-connected stages that overlap with each other
               and with the tail of partitioning
 
-Instrumentation: every phase is timed (busy + wall + CPU seconds) and every
-byte of file I/O counted, feeding the paper's Fig. 6 (phase breakdown) and
-Fig. 7 (I/O load) benchmarks; ``SortStats.overlap_seconds`` exposes the
-pipelining effect.  See DESIGN.md §1 for the stage graph.
+Instrumentation: every phase is timed (busy + wall seconds, and spans
+for its parts) and every byte of file I/O counted, feeding the paper's
+Fig. 6 (phase breakdown) and Fig. 7 (I/O load) benchmarks;
+``SortStats.overlap_seconds`` exposes the pipelining effect.  See
+DESIGN.md §1 for the stage graph.
 """
 
 from __future__ import annotations

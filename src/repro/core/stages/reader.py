@@ -338,29 +338,41 @@ def reader_worker(
                     # disk overflow zero-copy and keeps RAM fragments as
                     # piece lists — the per-partition join happens once,
                     # in take()
-                    pieces = bufs.pop(j)
-                    nbytes = buf_bytes.pop(j)
-                    total -= nbytes
-                    spills[j].append(
-                        stripe.index, seqs.get(j, 0), pieces, buf_recs.pop(j)
-                    )
-                    seqs[j] = seqs.get(j, 0) + 1
-                    clock.add_io(written=nbytes)
-
-                for block in fmt.iter_batches(
-                    input_path, stripe, cfg.batch_records
-                ):
-                    clock.add_io(read=block.n_bytes)
-                    bucket = partitioner.bucket_np(block.keys)
-                    # stable group-by-bucket, then contiguous fragment slices
-                    order = np.argsort(bucket, kind="stable")
-                    grouped = block.take(order)
-                    bcounts = np.bincount(bucket, minlength=n_partitions)
-                    starts = np.concatenate([[0], np.cumsum(bcounts)[:-1]])
-                    for j in np.nonzero(bcounts)[0]:
-                        frag = grouped.slice_bytes(
-                            starts[j], starts[j] + bcounts[j]
+                    with clock.span("partition.spill"):
+                        pieces = bufs.pop(j)
+                        nbytes = buf_bytes.pop(j)
+                        total -= nbytes
+                        spills[j].append(
+                            stripe.index, seqs.get(j, 0), pieces,
+                            buf_recs.pop(j),
                         )
+                        seqs[j] = seqs.get(j, 0) + 1
+                        clock.add_io(written=nbytes)
+
+                batches = fmt.iter_batches(
+                    input_path, stripe, cfg.batch_records
+                )
+                while True:
+                    with clock.span("partition.read"):
+                        block = next(batches, None)
+                    if block is None:
+                        break
+                    clock.add_io(read=block.n_bytes)
+                    with clock.span("partition.bucket"):
+                        bucket = partitioner.bucket_np(block.keys)
+                    # stable group-by-bucket, then contiguous fragment
+                    # slices
+                    with clock.span("partition.group"):
+                        order = np.argsort(bucket, kind="stable")
+                        grouped = block.take(order)
+                        bcounts = np.bincount(bucket, minlength=n_partitions)
+                        ends = np.cumsum(bcounts)
+                        frags = [
+                            (j, grouped.slice_bytes(ends[j] - bcounts[j],
+                                                    ends[j]))
+                            for j in np.nonzero(bcounts)[0]
+                        ]
+                    for j, frag in frags:
                         bufs.setdefault(j, []).append(frag)
                         buf_bytes[j] = buf_bytes.get(j, 0) + len(frag)
                         buf_recs[j] = buf_recs.get(j, 0) + int(bcounts[j])

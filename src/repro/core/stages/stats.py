@@ -5,6 +5,12 @@ returns; ``PhaseClock`` is the thread-safe accumulator the stage workers
 share while a sort is in flight.  Both predate the stage decomposition
 and keep their historical import paths (``repro.core.pipeline`` and
 ``repro.core.external`` re-export them).
+
+Every phase timer and every span (``PhaseClock.timer``/``span``,
+``ServeStats.span``) also opens a ``jax.profiler.TraceAnnotation`` named
+``elsar.<name>`` for its duration: under a profiler session the
+program's intervals land in the same trace as the device ops, on one
+clock.  With no session open an annotation costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -16,6 +22,52 @@ import time
 import numpy as np
 
 from repro.data import gensort
+
+SPAN_PREFIX = "elsar."
+
+
+def _annotation(name: str, meta: dict):
+    """A profiler annotation ``elsar.<name>`` (jax imported on first use,
+    so that importing this module stays free of JAX)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(SPAN_PREFIX + name, **meta)
+
+
+class _Interval:
+    """One timed interval: a profiler annotation around it, and its
+    ``perf_counter`` start and end handed to ``record(name, t0, t1)``
+    unless :meth:`discard` was called."""
+
+    __slots__ = ("_record", "name", "_meta", "_ann", "t0", "_discarded")
+
+    def __init__(self, record, name: str, meta: dict):
+        self._record = record
+        self.name = name
+        self._meta = meta
+        self._discarded = False
+
+    def discard(self) -> None:
+        """Drop this interval (e.g. an idle poll that did no phase work) —
+        otherwise empty polls would stretch the phase's wall span."""
+        self._discarded = True
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, self._meta)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if not self._discarded:
+            self._record(self.name, self.t0, t1)
+
+
+def _add_span(seconds: dict, counts: dict, name: str, dt: float) -> None:
+    seconds[name] = seconds.get(name, 0.0) + dt
+    counts[name] = counts.get(name, 0) + 1
 
 
 class LatencyReservoir:
@@ -102,6 +154,11 @@ class SortStats:
     so ``total_seconds > wall_seconds`` is the signature of phase overlap
     (paper Fig. 6's pipelining effect).
 
+    ``span_seconds``/``span_counts`` split phases into their parts
+    (``partition.read``, ``sort.pack``, ...; see :meth:`PhaseClock.span`):
+    busy seconds and interval counts per span, summed across workers.
+    Spans nest inside phases, so they stay out of ``total_seconds``.
+
     Executor accounting (DESIGN.md §10): ``device_dispatches`` counts
     jitted sort-graph launches, ``batch_occupancy`` is the mean fraction
     of super-batch slots holding real records, and ``jit_compiles`` the
@@ -120,7 +177,8 @@ class SortStats:
     n_readers: int = 1
     wall_seconds: float = 0.0
     phase_wall_seconds: dict = dataclasses.field(default_factory=dict)
-    phase_cpu_seconds: dict = dataclasses.field(default_factory=dict)
+    span_seconds: dict = dataclasses.field(default_factory=dict)
+    span_counts: dict = dataclasses.field(default_factory=dict)
     # set when the sort also emitted a query-serving sidecar (DESIGN.md §7)
     manifest_path: str | None = None
     # sort-executor accounting (DESIGN.md §10)
@@ -186,8 +244,14 @@ class ServeStats:
     ``n_shed`` counts admission-control rejections (the typed
     ``Overloaded`` path — under open-loop overload this climbs while
     p99 stays bounded).  Cache health: hit/miss/eviction counters plus
-    resident bytes of the partition-block LRU.  ``latencies_s`` is the
-    bounded :class:`LatencyReservoir` over submit→complete spans.
+    resident bytes of the partition-block LRU, and ``cache_load_bytes``,
+    the bytes copied into blocks on misses.  ``latencies_s`` is the
+    bounded :class:`LatencyReservoir` over submit→complete spans,
+    ``queue_wait_s`` the one over submit→popped-into-a-batch.
+
+    ``span_seconds``/``span_counts`` time the server's layers per batch
+    (``serve.execute``, ``serve.predict``, ``serve.search``,
+    ``serve.block_load``; see :meth:`span`).
     """
 
     n_point: int = 0
@@ -202,10 +266,28 @@ class ServeStats:
     cache_misses: int = 0
     cache_evictions: int = 0
     cache_bytes: int = 0
+    cache_load_bytes: int = 0
     latencies_s: LatencyReservoir = dataclasses.field(
         default_factory=LatencyReservoir
     )
+    queue_wait_s: LatencyReservoir = dataclasses.field(
+        default_factory=LatencyReservoir
+    )
+    span_seconds: dict = dataclasses.field(default_factory=dict)
+    span_counts: dict = dataclasses.field(default_factory=dict)
+    # number of the batch in execution: the server's batch loop sets it
+    # before handing the batch to its one execution thread
+    batch: int = 0
     wall_seconds: float = 0.0
+
+    def span(self, name: str) -> _Interval:
+        """Time one interval of the current batch's execution (the one
+        execution thread is the only caller); its annotation carries the
+        batch number, so the spans of one batch share an identifier."""
+        return _Interval(self._record_span, name, {"batch": self.batch})
+
+    def _record_span(self, name: str, t0: float, t1: float) -> None:
+        _add_span(self.span_seconds, self.span_counts, name, t1 - t0)
 
     @property
     def n_queries(self) -> int:
@@ -248,9 +330,13 @@ class ServeStats:
             "cache_evictions": self.cache_evictions,
             "cache_bytes": self.cache_bytes,
             "cache_hit_rate": self.cache_hit_rate,
+            "cache_load_bytes": self.cache_load_bytes,
             "qps": self.qps,
             "p50_ms": self.latency_ms(50),
             "p99_ms": self.latency_ms(99),
+            "queue_wait_p50_ms": self.queue_wait_s.percentile(50) * 1e3,
+            "queue_wait_p99_ms": self.queue_wait_s.percentile(99) * 1e3,
+            "span_seconds": dict(self.span_seconds),
             "wall_seconds": self.wall_seconds,
         }
 
@@ -271,24 +357,30 @@ class PhaseClock:
     """Thread-safe phase accounting shared by every stage worker.
 
     ``timer(phase)`` context-manages one busy interval: busy seconds are
-    summed per phase, wall spans are merged (min start / max end), and
-    thread CPU time is accumulated via ``time.thread_time``.  Integer
-    event counters (device dispatches, batch slots, ...) accumulate via
-    ``add_counter`` and land in ``finish``.
+    summed per phase and wall spans are merged (min start / max end).
+    ``span(name)`` times a part of a phase (a child interval inside a
+    ``timer``): its busy seconds and count are summed per name, apart
+    from the phases.  Integer event counters (device dispatches, batch
+    slots, ...) accumulate via ``add_counter``.  All of it lands in
+    ``finish``.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
         self.busy: dict[str, float] = {}
-        self.cpu: dict[str, float] = {}
-        self.span: dict[str, list[float]] = {}
+        self.wall: dict[str, list[float]] = {}
+        self.span_seconds: dict[str, float] = {}
+        self.span_counts: dict[str, int] = {}
         self.counters: dict[str, int] = {}
         self.bytes_read = 0
         self.bytes_written = 0
 
-    def timer(self, phase: str) -> "_PhaseTimer":
-        return _PhaseTimer(self, phase)
+    def timer(self, phase: str) -> _Interval:
+        return _Interval(self._record, phase, {})
+
+    def span(self, name: str) -> _Interval:
+        return _Interval(self._record_span, name, {})
 
     def add_io(self, read: int = 0, written: int = 0) -> None:
         with self._lock:
@@ -299,21 +391,25 @@ class PhaseClock:
         with self._lock:
             self.counters[name] = self.counters.get(name, 0) + value
 
-    def _record(self, phase: str, t0: float, t1: float, cpu_dt: float) -> None:
+    def _record(self, phase: str, t0: float, t1: float) -> None:
         with self._lock:
             self.busy[phase] = self.busy.get(phase, 0.0) + (t1 - t0)
-            self.cpu[phase] = self.cpu.get(phase, 0.0) + cpu_dt
-            span = self.span.setdefault(phase, [t0, t1])
-            span[0] = min(span[0], t0)
-            span[1] = max(span[1], t1)
+            wall = self.wall.setdefault(phase, [t0, t1])
+            wall[0] = min(wall[0], t0)
+            wall[1] = max(wall[1], t1)
+
+    def _record_span(self, name: str, t0: float, t1: float) -> None:
+        with self._lock:
+            _add_span(self.span_seconds, self.span_counts, name, t1 - t0)
 
     def finish(self, stats: SortStats) -> None:
         stats.wall_seconds = time.perf_counter() - self._t0
         stats.phase_seconds = dict(self.busy)
-        stats.phase_cpu_seconds = dict(self.cpu)
         stats.phase_wall_seconds = {
-            p: s[1] - s[0] for p, s in self.span.items()
+            p: s[1] - s[0] for p, s in self.wall.items()
         }
+        stats.span_seconds = dict(self.span_seconds)
+        stats.span_counts = dict(self.span_counts)
         stats.bytes_read += self.bytes_read
         stats.bytes_written += self.bytes_written
         # executor counters (pushed by core/executor.py implementations)
@@ -324,28 +420,3 @@ class PhaseClock:
                 self.counters.get("batch_records", 0) / slots
             )
         stats.jit_compiles += self.counters.get("jit_compiles", 0)
-
-
-class _PhaseTimer:
-    def __init__(self, clock: PhaseClock, phase: str):
-        self.clock, self.phase = clock, phase
-        self._discarded = False
-
-    def discard(self) -> None:
-        """Drop this interval (e.g. an idle poll that did no phase work) —
-        otherwise empty polls would stretch the phase's wall span."""
-        self._discarded = True
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        self.c0 = time.thread_time()
-        return self
-
-    def __exit__(self, *exc):
-        if not self._discarded:
-            self.clock._record(
-                self.phase,
-                self.t0,
-                time.perf_counter(),
-                time.thread_time() - self.c0,
-            )

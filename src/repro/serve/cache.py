@@ -88,8 +88,10 @@ class PartitionBlockCache:
                 self.stats.cache_hits += 1
                 return blk
             self.stats.cache_misses += 1
-        blk = self._load_block(index, int(pid))
+        with self.stats.span("serve.block_load"):
+            blk = self._load_block(index, int(pid))
         with self._lock:
+            self.stats.cache_load_bytes += blk.nbytes
             if blk.nbytes <= self.capacity_bytes:
                 self._blocks[key] = blk
                 self.stats.cache_bytes += blk.nbytes

@@ -28,12 +28,18 @@ including ties beyond the 8-byte numeric embedding.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 
 import numpy as np
 
 from repro.core import encoding, manifest as manifest_lib, rmi
 from repro.core.format import line_keys
+from repro.core.stages.stats import ServeStats
+
+
+def _span(stats: "ServeStats | None", name: str):
+    return contextlib.nullcontext() if stats is None else stats.span(name)
 
 
 class _ClosedBlock:
@@ -305,21 +311,29 @@ class SortedFileIndex:
     # -- queries -------------------------------------------------------
 
     def lookup(
-        self, keys: np.ndarray, *, use_kernels: bool = False
+        self,
+        keys: np.ndarray,
+        *,
+        use_kernels: bool = False,
+        stats: "ServeStats | None" = None,
     ) -> tuple[np.ndarray, np.ndarray]:
         """Batched point lookup of (B, key_width) u8 padded keys.
 
         Returns ``(rows, found)``: the row of the *first* record matching
-        each key (lower bound when absent) and a boolean hit mask.
+        each key (lower bound when absent) and a boolean hit mask.  With
+        ``stats`` (the server's), the prediction and the banded search
+        are timed as the spans ``serve.predict`` and ``serve.search``.
         """
-        preds = self.predict_positions(keys, use_kernels=use_kernels)
-        rows = np.empty(keys.shape[0], dtype=np.int64)
-        found = np.zeros(keys.shape[0], dtype=bool)
-        for i in range(keys.shape[0]):
-            q = keys[i, : self.key_width].tobytes()
-            r = self._bound(q, int(preds[i]), "left")
-            rows[i] = r
-            found[i] = r < self.n and self._key_at(r) == q
+        with _span(stats, "serve.predict"):
+            preds = self.predict_positions(keys, use_kernels=use_kernels)
+        with _span(stats, "serve.search"):
+            rows = np.empty(keys.shape[0], dtype=np.int64)
+            found = np.zeros(keys.shape[0], dtype=bool)
+            for i in range(keys.shape[0]):
+                q = keys[i, : self.key_width].tobytes()
+                r = self._bound(q, int(preds[i]), "left")
+                rows[i] = r
+                found[i] = r < self.n and self._key_at(r) == q
         return rows, found
 
     def range_bounds(self, lo_key: bytes, hi_key: bytes) -> tuple[int, int]:

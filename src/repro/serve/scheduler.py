@@ -148,6 +148,8 @@ class FifoBatchScheduler:
         batch = [
             self._q.popleft() for _ in range(min(self.max_batch, depth))
         ]
+        popped = self._clock()
+        self.stats.queue_wait_s.extend([popped - r.t_submit for r in batch])
         self.stats.n_batches += 1
         self.stats.batched_requests += len(batch)
         self.stats.queue_depth_sum += depth

@@ -183,6 +183,7 @@ class QueryServer:
             batch = await self.scheduler.next_batch()
             if batch is None:
                 return
+            self.stats.batch = self.stats.n_batches
             try:
                 results = await loop.run_in_executor(
                     None, self._execute, batch
@@ -204,7 +205,12 @@ class QueryServer:
     def _execute(self, batch: "list[Request]"):
         """One coalesced dispatch (worker thread): points grouped per
         shard for a single vectorized predict, ranges split per shard.
-        Returns ``[(request, response_dict), ...]``."""
+        Returns ``[(request, response_dict), ...]``.  Timed as the span
+        ``serve.execute``."""
+        with self.stats.span("serve.execute"):
+            return self._execute_batch(batch)
+
+    def _execute_batch(self, batch: "list[Request]"):
         out: dict[int, dict] = {}
         by_shard: dict[int, list] = {}
         for req in batch:
@@ -221,7 +227,7 @@ class QueryServer:
                 dtype=np.uint8,
             ).reshape(len(reqs), self.key_width)
             rows, found = index.lookup(
-                keys, use_kernels=self.config.use_kernels
+                keys, use_kernels=self.config.use_kernels, stats=self.stats
             )
             records = (
                 self.cache.fetch_rows(index, rows, found)
