@@ -99,10 +99,6 @@ class RecordBlock:
             except BufferError:  # a caller still holds a view
                 pass
 
-    def slice_bytes(self, lo: int, hi: int) -> bytes:
-        """Raw bytes of records ``[lo, hi)`` — contiguous by construction."""
-        return self.data[self.offsets[lo] : self.offsets[hi]].tobytes()
-
     def slice_records(self, lo: int, hi: int) -> "RecordBlock":
         """Records ``[lo, hi)`` as a sub-block.  ``data`` stays a view of
         this block's buffer (mmap-backed blocks never copy here), offsets

@@ -251,11 +251,12 @@ class PartitionSpill:
         self._n_seen = committed
         return done
 
-    def take(self) -> tuple[bytes | None, int]:
+    def take(self) -> tuple[np.ndarray | None, int]:
         """Finalize after ``close_writer``: returns (blob, fresh_bytes).
 
-        The blob holds the partition's record bytes in global input order
-        (fragments sorted by (stripe, seq)); the spill file is deleted.
+        The blob is a ``uint8`` array of the partition's record bytes in
+        global input order (fragments sorted by (stripe, seq)); the spill
+        file is deleted.
         ``fresh_bytes`` counts only bytes first seen by *this* call, so
         prefetched bytes are never double-counted.
         """
@@ -276,7 +277,10 @@ class PartitionSpill:
                 parts.extend(self._mem[i])
             else:
                 parts.append(self._loaded[i])
-        blob = b"".join(parts)
+        # a NumPy copy drops the GIL, which bytes.join keeps for any piece
+        # that is not exact bytes (the grouping's arrays): the sorter
+        # thread packs the previous partition while this one is joined
+        blob = np.concatenate([np.frombuffer(p, np.uint8) for p in parts])
         if self._ram is not None and self._mem:
             self._ram.release(
                 sum(self.segments[i][3] for i in self._mem)
@@ -284,6 +288,37 @@ class PartitionSpill:
         self._mem.clear()
         self._loaded.clear()
         return blob, fresh
+
+
+def group_fragments(block, bucket: np.ndarray, n_partitions: int):
+    """Stable group-by-bucket of one batch: ``(counts, frags, copied)``,
+    ``frags`` holding ``(j, record bytes)`` per non-empty partition ``j``
+    in input order and ``copied`` the record bytes written to make them.
+
+    The order is a stable argsort of the ids (a linear radix sort on
+    16-bit ids).  Fixed-stride blocks copy once: one ``np.take`` per
+    fragment over the batch viewed as ``V{stride}`` items, each fragment
+    its own allocation (a view of one grouped batch would keep all of it
+    alive while any fragment waits in the RAM spill).  Variable-length
+    blocks gather the whole batch, then copy each fragment out: twice.
+    """
+    counts = np.bincount(bucket, minlength=n_partitions)
+    ids = bucket.astype(np.uint16) if n_partitions <= 1 << 16 else bucket
+    order = np.argsort(ids, kind="stable")
+    ends = np.cumsum(counts)
+    ranges = [(j, ends[j] - counts[j], ends[j]) for j in np.nonzero(counts)[0]]
+    lengths = np.diff(block.offsets)
+    if lengths.size and (lengths == lengths[0]).all():
+        recs = np.ascontiguousarray(block.data[: block.n_bytes])
+        recs = recs.view(f"V{lengths[0]}")
+        frags = [(j, np.take(recs, order[lo:hi]).view(np.uint8))
+                 for j, lo, hi in ranges]
+        return counts, frags, block.n_bytes
+    grouped = block.take(order)
+    off = grouped.offsets
+    frags = [(j, grouped.data[off[lo] : off[hi]].tobytes())
+             for j, lo, hi in ranges]
+    return counts, frags, 2 * block.n_bytes
 
 
 def reader_worker(
@@ -306,8 +341,8 @@ def reader_worker(
     end, so no fragment ever spans a stripe boundary — the (stripe, seq)
     tag stays a total order over input positions.  The format supplies
     the blocks (fixed strides, or delimiter-split lines) and the
-    key-prefix matrix; everything below the key extraction is
-    layout-independent.
+    key-prefix matrix; below the key extraction only the grouping
+    (:func:`group_fragments`) looks at the layout, through the offsets.
     """
     n_partitions = len(spills)
     # with many partitions no single buffer may ever reach flush_bytes, so
@@ -324,9 +359,9 @@ def reader_worker(
             except queue.Empty:
                 return
             with clock.timer("partition"):
-                # fragments are buffered as bytes (not views) so a drained
-                # batch's memory is released as soon as the batch is routed
-                bufs: dict[int, list[bytes]] = {}
+                # fragments own their bytes (not views of the batch) so a
+                # drained batch's memory is released as soon as it is routed
+                bufs: dict[int, list] = {}
                 buf_bytes: dict[int, int] = {}
                 buf_recs: dict[int, int] = {}
                 seqs: dict[int, int] = {}
@@ -360,18 +395,11 @@ def reader_worker(
                     clock.add_io(read=block.n_bytes)
                     with clock.span("partition.bucket"):
                         bucket = partitioner.bucket_np(block.keys)
-                    # stable group-by-bucket, then contiguous fragment
-                    # slices
                     with clock.span("partition.group"):
-                        order = np.argsort(bucket, kind="stable")
-                        grouped = block.take(order)
-                        bcounts = np.bincount(bucket, minlength=n_partitions)
-                        ends = np.cumsum(bcounts)
-                        frags = [
-                            (j, grouped.slice_bytes(ends[j] - bcounts[j],
-                                                    ends[j]))
-                            for j in np.nonzero(bcounts)[0]
-                        ]
+                        bcounts, frags, copied = group_fragments(
+                            block, bucket, n_partitions
+                        )
+                    clock.add_counter("partition.group_bytes", copied)
                     for j, frag in frags:
                         bufs.setdefault(j, []).append(frag)
                         buf_bytes[j] = buf_bytes.get(j, 0) + len(frag)

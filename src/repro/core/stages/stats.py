@@ -158,6 +158,9 @@ class SortStats:
     (``partition.read``, ``sort.pack``, ...; see :meth:`PhaseClock.span`):
     busy seconds and interval counts per span, summed across workers.
     Spans nest inside phases, so they stay out of ``total_seconds``.
+    ``counters`` holds every ``PhaseClock.add_counter`` total by name
+    (``partition.group_bytes``: record bytes the partition grouping
+    wrote).
 
     Executor accounting (DESIGN.md §10): ``device_dispatches`` counts
     jitted sort-graph launches, ``batch_occupancy`` is the mean fraction
@@ -179,6 +182,7 @@ class SortStats:
     phase_wall_seconds: dict = dataclasses.field(default_factory=dict)
     span_seconds: dict = dataclasses.field(default_factory=dict)
     span_counts: dict = dataclasses.field(default_factory=dict)
+    counters: dict = dataclasses.field(default_factory=dict)
     # set when the sort also emitted a query-serving sidecar (DESIGN.md §7)
     manifest_path: str | None = None
     # sort-executor accounting (DESIGN.md §10)
@@ -410,6 +414,7 @@ class PhaseClock:
         }
         stats.span_seconds = dict(self.span_seconds)
         stats.span_counts = dict(self.span_counts)
+        stats.counters = dict(self.counters)
         stats.bytes_read += self.bytes_read
         stats.bytes_written += self.bytes_written
         # executor counters (pushed by core/executor.py implementations)

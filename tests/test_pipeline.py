@@ -134,12 +134,125 @@ def test_spill_ram_disk_mix_matches_disk_only(tmp_path):
         sp.close_writer()
     blob_mixed, fresh_mixed = mixed.take()
     blob_disk, fresh_disk = disk.take()
+    blob_mixed, blob_disk = blob_mixed.tobytes(), blob_disk.tobytes()
     assert blob_mixed == blob_disk  # (stripe, seq) order, not arrival
     assert blob_mixed.startswith(b"A" * 200 + b"B" * 500 + b"C" * 50)
     # prefetch bytes + take bytes account every byte exactly once
     assert 600 + fresh_mixed == fresh_disk == total
     assert ram._used == 0  # budget returned after the drain
     assert not (tmp_path / "mix.spill").exists()
+
+
+def test_spill_ram_disk_mix_array_pieces(tmp_path):
+    """The partition grouping hands fragments over as owned ``uint8``
+    arrays: RAM-resident pieces, ``writev`` overflow and the ``take()``
+    join give the all-disk blob, and the budget drains to 0."""
+    from repro.core.stages import PartitionSpill, SpillBudget
+
+    rng = np.random.default_rng(5)
+    frags = [  # (stripe, seq, pieces) appended out of stripe order
+        (2, 0, [rng.integers(0, 256, 300, dtype=np.uint8)]),
+        (0, 0, [rng.integers(0, 256, 200, dtype=np.uint8), b"a" * 37]),
+        (1, 1, [rng.integers(0, 256, 100, dtype=np.uint8)]),
+        (0, 1, [rng.integers(0, 256, 500, dtype=np.uint8),
+                rng.integers(0, 256, 74, dtype=np.uint8)]),
+        (1, 0, [rng.integers(0, 256, 50, dtype=np.uint8)]),
+    ]
+    ram = SpillBudget(700)  # RAM holds a few; the rest overflow to disk
+    mixed = PartitionSpill(str(tmp_path / "mix.spill"), ram=ram)
+    disk = PartitionSpill(str(tmp_path / "disk.spill"))
+    for stripe, seq, pieces in frags:
+        mixed.append(stripe, seq, list(pieces), n_records=len(pieces))
+        disk.append(stripe, seq, list(pieces), n_records=len(pieces))
+    total = sum(len(p) for _, _, ps in frags for p in ps)
+    assert mixed.n_bytes == disk.n_bytes == total
+    assert 0 < ram.disk_bytes < total  # genuinely mixed placement
+    for sp in (mixed, disk):
+        sp.close_writer()
+    blob_mixed, fresh_mixed = mixed.take()
+    blob_disk, fresh_disk = disk.take()
+    in_order = sorted(frags, key=lambda f: f[:2])
+    expect = b"".join(bytes(p) for _, _, ps in in_order for p in ps)
+    assert blob_mixed.tobytes() == blob_disk.tobytes() == expect
+    assert fresh_mixed == fresh_disk == total
+    assert ram._used == 0  # budget returned after the drain
+
+
+def _grouping_before(block, bucket, n_partitions):
+    """The grouping the partition phase used to run: a stable argsort of
+    the int32 ids, a whole-batch ``take``, one ``tobytes`` per fragment."""
+    order = np.argsort(bucket, kind="stable")
+    grouped = block.take(order)
+    counts = np.bincount(bucket, minlength=n_partitions)
+    ends = np.cumsum(counts)
+    off = grouped.offsets
+    return counts, [
+        (j, grouped.data[off[ends[j] - counts[j]] : off[ends[j]]].tobytes())
+        for j in np.nonzero(counts)[0]
+    ]
+
+
+@pytest.mark.parametrize("stride", [100, 37])
+@pytest.mark.parametrize(
+    "n_partitions,ids",
+    [
+        (1, "all"),
+        (15, "all"),
+        (300, "all"),  # over 255: ids need more than 8 bits
+        (15, "gaps"),  # empty partitions between occupied ones
+        (300, "one"),  # every record in one partition
+    ],
+)
+def test_group_fragments_match_argsort_take(n_partitions, ids, stride):
+    from repro.core.format import FixedFormat
+    from repro.core.stages.reader import group_fragments
+
+    n = 5_000
+    rng = np.random.default_rng(n_partitions * stride)
+    mat = rng.integers(0, 256, (n, stride), dtype=np.uint8)
+    block = FixedFormat(stride, min(stride, 10))._block_from_matrix(mat)
+    bucket = {
+        "all": lambda: rng.integers(0, n_partitions, n),
+        "gaps": lambda: rng.choice([0, 3, 4, 14], n),
+        "one": lambda: np.full(n, n_partitions - 2),
+    }[ids]().astype(np.int32)
+
+    counts, frags, copied = group_fragments(block, bucket, n_partitions)
+    ref_counts, ref_frags = _grouping_before(block, bucket, n_partitions)
+    np.testing.assert_array_equal(counts, ref_counts)
+    assert [j for j, _ in frags] == [j for j, _ in ref_frags]
+    for (j, frag), (_, ref) in zip(frags, ref_frags):
+        assert frag.dtype == np.uint8 and len(frag) == counts[j] * stride
+        assert frag.tobytes() == ref
+        # each fragment its own allocation: never a view of the batch
+        assert not np.shares_memory(frag, block.data)
+    assert copied == n * stride  # one copy of every record
+
+
+def test_group_copies_each_record_once(runs):
+    """Fixed-stride sorts take the one-copy grouping: the counter reads
+    the input bytes exactly."""
+    for r, (_, stats) in runs.items():
+        assert stats.counters["partition.group_bytes"] == stats.input_bytes, r
+
+
+def test_line_sort_keeps_two_copy_grouping(tmp_path):
+    """Variable-length blocks keep the gather-then-slice grouping (two
+    copies of every record), and the output is the stable host sort."""
+    from repro.core.format import LineFormat
+    from repro.data import lines
+
+    inp, out = str(tmp_path / "in.txt"), str(tmp_path / "out.txt")
+    lines.write_lines(inp, 60_000, kind="uniform", seed=4, max_len=12)
+    raw = open(inp, "rb").read()
+    stats = external.sort_file(
+        inp, out, memory_budget_bytes=256 << 10, batch_records=20_000,
+        n_partitions=8, fmt=LineFormat(max_key_bytes=16),
+    )
+    assert len(stats.partition_counts) > 1
+    assert stats.counters["partition.group_bytes"] == 2 * len(raw)
+    recs = [ln + b"\n" for ln in raw[:-1].split(b"\n")]
+    assert open(out, "rb").read() == b"".join(sorted(recs))
 
 
 def test_record_stripes_partition_input():

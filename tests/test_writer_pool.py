@@ -273,7 +273,7 @@ def test_spill_pieces_append_matches_bytes(tmp_path):
         sp.close_writer()
     blob_j, _ = joined.take()
     blob_p, _ = pieces.take()
-    assert blob_j == blob_p
+    assert blob_j.tobytes() == blob_p.tobytes()
 
 
 def test_spill_root_resolution(tmp_path, monkeypatch):
