@@ -3,13 +3,13 @@ names ``BENCHMARK.json`` gives them, each in a file of its own:
 
 * a configuration: the ``file`` its entry names (``bench/configs/``);
 * a traffic mix: ``bench/mixes/<traffic>.json``, whose ``driver`` key
-  names the general driver that reads it (``sort_jobs`` or
-  ``open_loop``);
+  names the general driver that reads it, ``bench/harness/<driver>.py``
+  (``harness.main.driver_for``);
 * a per-layer metric: ``bench/metrics/<name>.py``, a small reader with
   ``read(run) -> float | None``.
 
-A later cell, configuration or metric is a new file and a new entry;
-no file that is here changes.
+A later cell, configuration, driver or metric is a new file and a new
+entry; no file that is here changes.
 """
 
 from __future__ import annotations

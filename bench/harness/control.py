@@ -13,8 +13,10 @@ device key would, and the numbers a run compares must fail it:
   and ``wrong_answers`` counts the answers that differ from the
   reference's.
 
-``python3 bench/control.py --workload <cell> --seeds a b c`` prints these
-readings at the cell's own size.
+Each driver computes the readings of its own comparison in its
+``control_readings`` hook, with the functions below.
+``python3 bench/control.py --workload <cell> --seeds a b c`` prints them
+at the cell's own size.
 """
 
 from __future__ import annotations
@@ -24,7 +26,7 @@ import types
 
 import numpy as np
 
-from harness import gensort, loadgen, open_loop, reference
+from harness import gensort, loadgen, main, open_loop, reference
 
 CONTROL_KEY_BYTES = 4
 
@@ -60,7 +62,8 @@ def serve_reading(records: np.ndarray, keys: np.ndarray) -> int:
 
 def readings(config: dict, mix: dict, seed: int, seconds: float,
              tmp: str) -> dict:
-    """The control's numbers for one seed of one cell, at its size."""
+    """The control's numbers for one seed of one cell, at its size: the
+    corpus the run would make, read by the mix's driver's hook."""
     path = os.path.join(tmp, f"control_{seed}.bin")
     gensort.write_corpus(path, config["records"], seed=seed,
                          skewed=config["key_distribution"] == "skewed",
@@ -69,11 +72,5 @@ def readings(config: dict, mix: dict, seed: int, seconds: float,
         records = np.array(gensort.read_corpus(path))
     finally:
         os.unlink(path)
-    if mix["driver"] == "sort_jobs":
-        return {"wrong_records": sort_reading(records)}
-    run = types.SimpleNamespace(mix=mix, seconds=seconds, seed=seed)
-    target = types.SimpleNamespace(
-        records=records, index=types.SimpleNamespace(key_width=10)
-    )
-    _, keys = open_loop.schedule(run, target)
-    return {"wrong_answers": serve_reading(records, keys)}
+    return main.driver_for(mix).control_readings(config, mix, seed, seconds,
+                                                 records)

@@ -7,15 +7,22 @@
    exits 3 before anything is made, and prints no result.
 3. Point JAX's persistent compile cache at ``$JAX_COMPILATION_CACHE_DIR``
    when set, else at ``<checkout>/.jax_cache``.
-4. Hand the run to the mix's driver: it makes the inputs from the seed,
-   warms up every shape (set-up), measures for ``--seconds``, then
-   compares what the window produced with the plain reference.
+4. Hand the run to the mix's driver (:func:`driver_for`): it makes the
+   inputs from the seed, warms up every shape (set-up), measures for
+   ``--seconds``, then compares what the window produced with the plain
+   reference.
 5. Print the set-up split and the compile counts as ``key=value`` lines,
    each compared number beside its limit as the last lines of standard
    error, and as the last line of standard output one JSON object:
    ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
    end-to-end metrics, or with ``--trace 1`` its per-layer metrics),
    ``device``, with ``--trace 1`` a ``breakdown``, and last ``checks``.
+
+A driver is a module of this package, ``bench/harness/<driver>.py``,
+named by the mix's ``driver`` key.  It has ``drive(run, devices)``, and
+``control_readings(config, mix, seed, seconds, records)``, the numbers
+its run compares as the control gives them (:mod:`harness.control`).  A
+later kind of traffic brings its driver as a new file there.
 
 Scratch files (corpus, sorted outputs, spills, the trace) live in a new
 temporary directory under ``$TMPDIR``, removed at exit.
@@ -26,13 +33,14 @@ from __future__ import annotations
 import argparse
 import importlib
 import json
+import os
 import shutil
 import tempfile
 import time
 
 from harness import catalog, runtime, shapes
 
-DRIVERS = ("sort_jobs", "open_loop")
+HARNESS_DIR = os.path.dirname(os.path.abspath(__file__))
 
 
 def parse_args(argv):
@@ -45,10 +53,16 @@ def parse_args(argv):
 
 
 def driver_for(mix: dict):
+    """The module ``harness.<driver>`` that the mix names, found as the
+    file ``bench/harness/<driver>.py`` with a ``drive`` function."""
     name = mix["driver"]
-    if name not in DRIVERS:
-        raise KeyError(f"mix names driver {name!r}; known: {DRIVERS}")
-    return importlib.import_module(f"harness.{name}")
+    path = os.path.join(HARNESS_DIR, f"{name}.py")
+    if not os.path.isfile(path):
+        raise KeyError(f"mix names driver {name!r}: no file {path}")
+    driver = importlib.import_module(f"harness.{name}")
+    if not callable(getattr(driver, "drive", None)):
+        raise KeyError(f"driver {path} has no drive(run, devices)")
+    return driver
 
 
 def main(argv=None, *, t_start: "float | None" = None,

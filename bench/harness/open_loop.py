@@ -16,7 +16,8 @@ counts as slower than any limit.  The client waits for answers up to
 :data:`ANSWER_GRACE_S` past the window's close.
 
 Once the window has closed, every answer is compared with the plain
-reference's answer for its key.
+reference's answer for its key; under the control that comparison reads
+``wrong_answers`` (:func:`control_readings`).
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ import asyncio
 import os
 import sys
 import time
+import types
 
 import numpy as np
 
@@ -238,3 +240,16 @@ def drive(run, devices) -> None:
         n_leaf=int(np.asarray(
             target.index.manifest.model.leaf_slope).shape[0]),
     )
+
+
+def control_readings(config, mix, seed, seconds, records) -> dict:
+    """``wrong_answers`` of the control's answers to the keys a run of
+    ``seconds`` sends over the corpus ``records``."""
+    from harness import control
+
+    run = types.SimpleNamespace(mix=mix, seconds=seconds, seed=seed)
+    target = types.SimpleNamespace(
+        records=records, index=types.SimpleNamespace(key_width=10)
+    )
+    _, keys = schedule(run, target)
+    return {"wrong_answers": control.serve_reading(records, keys)}

@@ -73,8 +73,8 @@ class CompileClock:
 
 class Run:
     """One run of one cell: its inputs, scratch directory, clocks and
-    window.  The drivers (``harness.sort_jobs``, ``harness.open_loop``)
-    fill in the outcome."""
+    window.  The mix's driver (``harness.<driver>``) fills in the
+    outcome."""
 
     def __init__(self, *, workload: dict, config: dict, mix: dict,
                  seed: int, seconds: float, trace: bool, tmp: str,

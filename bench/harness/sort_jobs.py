@@ -12,7 +12,8 @@ end of the job that crosses ``--seconds``; no partial job counts.
 the window's start to the end of its last job.
 
 Once the window has closed, every output byte of every job is compared
-with the reference's stable memcmp sort of the corpus.
+with the reference's stable memcmp sort of the corpus; under the control
+that comparison reads ``wrong_records`` (:func:`control_readings`).
 """
 
 from __future__ import annotations
@@ -119,3 +120,10 @@ def drive(run, devices) -> None:
         0,
     )
     run.log("wrong_records_per_job", wrong)
+
+
+def control_readings(config, mix, seed, seconds, records) -> dict:
+    """``wrong_records`` of the control's sort of the corpus ``records``."""
+    from harness import control
+
+    return {"wrong_records": control.sort_reading(records)}

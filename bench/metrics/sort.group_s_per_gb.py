@@ -1,7 +1,9 @@
-"""Partition phase (``core/stages/reader``), its grouping: the stable
-argsort by bucket, the record ``take``, the bincount and the fragment
-slices (span ``partition.group``), busy seconds per input GB summed over
-the window's jobs (``SortStats.span_seconds``)."""
+"""Partition phase (``core/stages/reader``), its grouping
+(``reader.group_fragments``, span ``partition.group``): the bincount, a
+stable radix argsort of the 16-bit bucket ids, and one ``np.take`` per
+fragment over the fixed-stride records (one copy of each record; line
+blocks gather the batch, then copy each fragment out), busy seconds per
+input GB summed over the window's jobs (``SortStats.span_seconds``)."""
 
 SPAN = "partition.group"
 

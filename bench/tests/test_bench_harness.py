@@ -255,6 +255,128 @@ def test_new_config_mix_and_metric_found_by_name(checkout, monkeypatch):
     assert {k: v for k, v in after.items() if k in before} == before
 
 
+NEW_DRIVER = '''"""Whole distributed sorts of the corpus, on a one-device mesh."""
+
+import os
+
+from harness import control, gensort, reference, sort_jobs
+
+
+def job(run, inp, out):
+    from repro.core import terasort
+    from repro.launch.mesh import make_data_mesh
+
+    with run.span("bench.job"):
+        return terasort.sort_file_distributed(
+            inp, out, make_data_mesh(1), executor="mesh", workdir=run.tmp,
+            chunk_records=1 << 13)
+
+
+def drive(run, devices):
+    inp = sort_jobs.make_corpus(run)
+    out = os.path.join(run.tmp, "out.bin")
+    with run.setup_phase("warmup"):
+        job(run, inp, out)
+    t0 = run.window_begin()
+    stats = job(run, inp, out)
+    run.window_end()
+    run.read_memory(devices)
+    records = gensort.read_corpus(inp)
+    order, _ = reference.stable_order(records)
+    wrong = reference.mismatched_records(gensort.read_corpus(out), records,
+                                         order)
+    run.attempted, run.failed = 1, int(wrong > 0)
+    run.checks["wrong_records"] = (wrong, 0)
+    run.checks["jobs_off_mesh"] = (int(stats.executor != "mesh"), 0)
+    run.e2e["sort_mb_s"] = stats.input_bytes / 1e6 / (run.window[1] - t0)
+
+
+def control_readings(config, mix, seed, seconds, records):
+    return {"wrong_records": control.sort_reading(records)}
+'''
+
+RUN_NEW_DRIVER = f'''
+import json, sys, tempfile, types
+sys.path.insert(0, "bench")
+sys.path.insert(1, {os.path.join(CHECKOUT, "src")!r})
+from harness import catalog, control, main, runtime, tracereduce
+cat = catalog.Catalog(".", "bench")
+if sys.argv[2] == "control":
+    w = cat.workload(sys.argv[1])
+    with tempfile.TemporaryDirectory() as tmp:
+        print(json.dumps(control.readings(
+            cat.config(w["config"]), cat.mix(w["traffic"]), {SEED},
+            cat.spec["run_seconds"], tmp)))
+    sys.exit(0)
+runtime.Run.reduce_trace = lambda self: setattr(
+    self, "reduced", tracereduce.reduce({SMALL_TRACE!r}))
+dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite",
+                            memory_stats=lambda: {{"peak_bytes_in_use": 1}})
+sys.exit(main.main(
+    ["--workload", sys.argv[1], "--seed", str({SEED}), "--seconds", "0.1",
+     "--trace", sys.argv[2]],
+    check_devices=lambda n: [dev] * n, cat=cat))
+'''
+
+
+def test_new_driver_found_by_name(checkout, tmp_path):
+    """A driver that is a new file under ``bench/harness/``, run on a
+    four-chip cell, from a checkout that holds it and nothing else new."""
+    with pytest.raises(KeyError, match=r"no_such_driver\.py"):
+        main.driver_for({"driver": "no_such_driver"})
+    before = _digests(checkout / "bench")
+    (checkout / "bench/harness/dist_sort_jobs.py").write_text(NEW_DRIVER)
+    (checkout / "bench/mixes/dist_sort.json").write_text(json.dumps({
+        "driver": "dist_sort_jobs", "what": "test",
+    }))
+    (checkout / "bench/metrics/sort.exposed_sort_s.py").write_text(
+        "def read(run):\n"
+        "    if run.reduced is None:\n"
+        "        return None\n"
+        "    return sum(run.reduced.exposed_seconds(r'sort'))\n"
+    )
+    spec = json.loads((checkout / "BENCHMARK.json").read_text())
+    cell = "indy_skew_1g.dist_sort"  # key ties cross the mesh route
+    spec["workloads"].append({
+        "name": cell, "config": "indy_skew_1g", "traffic": "dist_sort",
+        "chips": 4, "why": "test",
+    })
+    for m in spec["end_to_end"]:
+        if m["name"] == "sort_mb_s":
+            m["workloads"].append(cell)
+    spec["per_layer"].append({
+        "name": "sort.exposed_sort_s", "unit": "s", "better": "lower",
+        "source": "device_trace", "layer": "device", "moves": "sort_mb_s",
+        "workloads": [cell],
+    })
+    (checkout / "BENCHMARK.json").write_text(json.dumps(spec))
+    script = tmp_path / "run_new_driver.py"
+    script.write_text(RUN_NEW_DRIVER)
+    results = {}
+    for mode in ("0", "1", "control"):
+        proc = subprocess.run(
+            [sys.executable, str(script), cell, mode], cwd=checkout,
+            env=_env_without_path(), capture_output=True, text=True,
+            timeout=600,
+        )
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        results[mode] = json.loads(proc.stdout.strip().splitlines()[-1])
+    for result in (results["0"], results["1"]):
+        assert result["correct"] is True
+        assert result["device"]["count"] == 4
+        assert result["checks"]["wrong_records"] == {"value": 0, "limit": 0}
+        assert result["checks"]["jobs_off_mesh"] == {"value": 0, "limit": 0}
+    assert set(results["0"]["metrics"]) == {"sort_mb_s", "setup_s"}
+    exposed = results["1"]["metrics"]["sort.exposed_sort_s"]["value"]
+    assert exposed == pytest.approx(
+        sum(tracereduce.reduce(SMALL_TRACE).exposed_seconds("sort")))
+    assert exposed > 0
+    # the new driver's own hook gives the control's sort reading
+    assert results["control"] == {"wrong_records": 19_978}
+    after = _digests(checkout / "bench")
+    assert {k: v for k, v in after.items() if k in before} == before
+
+
 # ---------------------------------------------------------------------------
 # the open-loop client times each request from its due time
 # ---------------------------------------------------------------------------
@@ -344,6 +466,23 @@ def test_control_fails_the_lookup_comparison():
         "records": records[ref_rows], "record_len": np.full(len(keys), 100),
     }
     assert open_loop.wrong_answers(target, keys, exact) == (0, 0)
+
+
+@pytest.mark.parametrize("workload,want", [
+    (SORT_CELLS[0], {"wrong_records": 218}),
+    (SORT_CELLS[1], {"wrong_records": 199_974}),
+    (SERVE_CELL, {"wrong_answers": 1}),
+])
+def test_control_readings_through_the_drivers_hooks(checkout, tmp_path,
+                                                    workload, want):
+    """``control.readings`` asks the mix's driver, and reads what it read
+    when it chose the comparison itself (200,000 records, 50 q/s)."""
+    cat = catalog.Catalog(str(checkout), str(checkout / "bench"))
+    w = cat.workload(workload)
+    config = dict(cat.config(w["config"]), records=200_000)
+    got = control.readings(config, cat.mix(w["traffic"]), SEED,
+                           cat.spec["run_seconds"], str(tmp_path))
+    assert got == want
 
 
 def _corrupt(path):
@@ -459,6 +598,15 @@ def test_reduce_recorded_chip_trace():
         hbm)
     assert 0 < sort_share <= 100 and 0 < rmi_share <= 100
     assert all(s > 0 for _, s in b["device_ops"])
+    # the inputs of every metric reader, as the reduction read them
+    # before it kept each device's ops
+    assert r.window_s == 0.0179652 and r.busy_s == 9.2008e-05
+    assert r.module_ns == {"jit_rmi_bucket": 85885.0, "jit__flat_impl": 10141.0}
+    assert sum(r.op_ns.values()) == 92008.0 and r.op_ns["sort.21"] == 8842.0
+    assert [s for _, s in r.gaps[:4]] == [
+        0.004775648, 0.004687267, 0.004287686, 0.004118603]
+    assert len(r.gaps) == 67 and len(r.device_ops) == 1
+    assert sum(b - a for _, a, b in r.device_ops[0]) == 92008.0
 
 
 class _Ev:
@@ -475,11 +623,17 @@ def _plane(name, lines):
 
 
 def test_reduce_arithmetic_on_a_synthetic_trace():
-    host = _plane("/host:CPU", {"main": [
-        _Ev("bench.window", 100, 1000),
-        _Ev("bench.job", 100, 500),
-        _Ev("bench.reference", 600, 400),
-    ]})
+    host = _plane("/host:CPU", {
+        "main": [
+            _Ev("bench.window", 100, 1000),
+            _Ev("bench.job", 100, 500),
+            _Ev("bench.reference", 600, 400),
+            _Ev("elsar.sort", 100, 490),
+        ],
+        # the program's spans on another thread: the shortest one open at
+        # a gap's midpoint names the gap's phase
+        "worker": [_Ev("elsar.sort.gather", 110, 30)],
+    })
     dev = _plane("/device:TPU:0", {
         "XLA Modules": [_Ev("jit__flat_impl(7)", 150, 300),
                         _Ev("jit_rmi_bucket(2)", 900, 400)],
@@ -493,16 +647,61 @@ def test_reduce_arithmetic_on_a_synthetic_trace():
     assert r.idle_share == pytest.approx(0.5)
     assert r.module_seconds("_flat_impl") == pytest.approx(300e-9)
     assert r.module_seconds("rmi_bucket") == pytest.approx(200e-9)
+    # [450, 900) lies in no elsar. span; [100, 150) in two
     assert r.gaps[0] == ("bench.reference", pytest.approx(450e-9))
-    assert ("bench.job", pytest.approx(50e-9)) in r.gaps
+    assert r.gaps[1] == ("bench.job/sort.gather", pytest.approx(50e-9))
+    assert len(r.gaps) == 2
     ops = dict(r.breakdown()["device_ops"])
     assert ops["custom-call.3"] == pytest.approx(200e-9)
     assert "early" not in ops
 
 
+def test_reduce_keeps_each_devices_ops():
+    """Four device planes, listed out of order: ``device_ops`` per plane
+    in device order, and the time of an ``all-to-all`` that a fusion
+    half covers on device 0 and nothing covers on device 1."""
+    host = _plane("/host:CPU", {"main": [_Ev("bench.window", 0, 1000)]})
+    a2a = "%all-to-all.1 = (u32[64]{0}) all-to-all(u32[64]{0} %p), dims={0}"
+
+    def tpu(n, ops):
+        return _plane(f"/device:TPU:{n}", {"XLA Ops": ops})
+
+    planes = [
+        host,
+        tpu(1, [_Ev(a2a, 100, 200), _Ev("fusion.2", 500, 200)]),
+        tpu(0, [_Ev(a2a, 100, 200), _Ev("fusion.2", 200, 200)]),
+        tpu(3, [_Ev(a2a, 900, 200)]),  # runs past the window's end
+        tpu(2, [_Ev("fusion.2", 0, 250)]),
+    ]
+    r = tracereduce.reduce_profile(types.SimpleNamespace(planes=planes))
+    assert r.n_devices == 4
+    assert r.device_ops == [
+        [("all-to-all.1", 100, 300), ("fusion.2", 200, 400)],
+        [("all-to-all.1", 100, 300), ("fusion.2", 500, 700)],
+        [("fusion.2", 0, 250)],
+        [("all-to-all.1", 900, 1000)],
+    ]
+    assert r.op_seconds("all-to-all") == pytest.approx(
+        [200e-9, 200e-9, 0.0, 100e-9])
+    assert r.exposed_seconds("all-to-all") == pytest.approx(
+        [100e-9, 200e-9, 0.0, 100e-9])
+    assert r.op_seconds("fusion") == pytest.approx(
+        [200e-9, 200e-9, 250e-9, 0.0])
+    assert r.exposed_seconds("fusion") == pytest.approx(
+        [100e-9, 200e-9, 250e-9, 0.0])
+    # busy: [100, 400), [100, 300) + [500, 700), [0, 250), [900, 1000)
+    assert r.busy_s == pytest.approx((300 + 400 + 250 + 100) / 4 * 1e-9)
+    # summed over the planes, as before
+    assert dict(r.breakdown()["device_ops"]) == pytest.approx(
+        {"all-to-all.1": 500e-9, "fusion.2": 650e-9})
+    # idle gaps are device 0's: [0, 100) and [400, 1000)
+    assert [s for _, s in r.gaps] == pytest.approx([600e-9, 100e-9])
+
+
 def test_peaks_table():
     p = shapes.peaks("TPU v5 lite")
     assert p["hbm_bytes_per_s"] == 819e9 and p["bf16_flops_per_s"] == 197e12
+    assert p["ici_bytes_per_s"] == 200e9 and "1,600 Gbps" in p["source"]
     with pytest.raises(KeyError):
         shapes.peaks("TPU v4")
 
