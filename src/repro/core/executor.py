@@ -453,15 +453,23 @@ class MeshBatchedExecutor(_SuperBatchExecutor):
     spreads the packed segments over every device of a jax mesh: block
     ``i`` of a dispatch group is assigned to the least-loaded device
     (ties resolve in device order, so ``n_dev`` equal-sized key ranges
-    land on their owner devices), each device's shard is padded to a
-    shared sixteenth-octave :func:`~repro.kernels.fused.pad_target`
-    width, and ONE jitted ``shard_map`` launch sorts every device's
-    segments locally — the flat stable ``(seg, hi, lo)`` comparison
+    that share one dispatch land on their owner devices), each device's
+    shard is padded to a shared sixteenth-octave
+    :func:`~repro.kernels.fused.pad_target` width, and ONE jitted
+    ``shard_map`` launch (module ``jit_mesh_flat_sort``) sorts every
+    device's segments locally — the flat stable ``(seg, hi, lo)`` comparison
     graph of DESIGN.md §12, which is byte-identical to the host path by
     the same argument (pure-jnp encode, stable ties, memcmp touch-up in
     the epilogue).  No collective runs inside the program: records were
     already routed to their owner ranges, so the sort is embarrassingly
     device-local — the paper's merge-free invariant at mesh scale.
+
+    Ranges share a dispatch only while together they stay under the
+    ``batch_slots`` and ``batch_bytes`` caps.  A range that reaches a
+    cap alone fills its dispatch alone, lands on device 0 (the least
+    loaded of an empty dispatch), and leaves the other devices sorting
+    padding: the distributed sorter's ranges at GraySort sizes (millions
+    of records a device) all go to device 0, one dispatch each.
 
     Occupancy/dispatch accounting matches the single-device executor:
     one dispatch covers ``n_dev * n_pad`` slots, and padded slots (both
@@ -512,7 +520,7 @@ class MeshBatchedExecutor(_SuperBatchExecutor):
 
             from repro.kernels import fused
 
-            def local_fn(keys, seg):
+            def mesh_flat_sort(keys, seg):
                 # local shapes: keys (1, n_pad, 8), seg (1, n_pad)
                 perm = fused._flat_impl(
                     keys.reshape(n_pad, -1), seg.reshape(n_pad)
@@ -522,7 +530,7 @@ class MeshBatchedExecutor(_SuperBatchExecutor):
             spec = P(self.axis_names)
             fn = jax.jit(
                 jax.shard_map(
-                    local_fn,
+                    mesh_flat_sort,
                     mesh=self.mesh,
                     in_specs=(spec, spec),
                     out_specs=spec,
@@ -540,7 +548,7 @@ class MeshBatchedExecutor(_SuperBatchExecutor):
         from repro.kernels import fused
 
         # least-loaded device assignment, stable in arrival order: the
-        # i-th of n_dev equal ranges lands on device i (its owner)
+        # i-th of n_dev equal ranges in one dispatch lands on device i
         dev_entries: list = [[] for _ in range(self.n_dev)]
         dev_load = [0] * self.n_dev
         for tag, b in entries:

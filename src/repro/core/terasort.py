@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import encoding, rmi
+from repro.core import encoding, partition, rmi
 from repro.core import manifest as manifest_lib
 from repro.core.executor import make_executor
 from repro.core.format import GENSORT, RecordFormat
@@ -115,7 +115,11 @@ def sort_file_distributed(
         stats.bytes_read += int(idx.shape[0] * src.keys.shape[1])
 
     # --- chunk loop: pod partitions each chunk to its owner devices
-    chunk_records = max((chunk_records // n_dev) * n_dev, n_dev)
+    # the route's block transpose splits each device's rows into n_dev
+    # blocks, so a chunk is a whole number of n_dev * n_dev rows
+    step = n_dev * n_dev
+    chunk_records = max((chunk_records // step) * step, step)
+    per_device = chunk_records // n_dev
     sh = NamedSharding(mesh, P(axis_names))
     # per-host spill placement (§15): REPRO_SPILL_DIR (or workdir) with
     # a host<k> subdir, so each process of a pod spills to storage it
@@ -138,52 +142,76 @@ def sort_file_distributed(
         def route(hi, lo, val, factor):
             if factor not in route_fns:
                 route_fns[factor] = _make_route_fn(
-                    mesh, axis_names, model, chunk_records // n_dev, factor
+                    mesh, axis_names, model, per_device, factor
                 )
             return route_fns[factor](hi, lo, val)
 
+        # the partition phase splits into three spans a chunk on the
+        # clock: host encode, the pod exchange (device_put, the route
+        # program, its overflow check and retries, the fetch), and the
+        # host spill of each device's rows to its range file
         with clock.timer("partition"):
             for off in range(0, n, chunk_records):
                 cb = src.slice_records(off, min(off + chunk_records, n))
                 m = cb.n_records
                 stats.bytes_read += cb.n_bytes
-                hi, lo = encoding.encode_np(cb.keys)
-                pad = (-m) % n_dev
-                if pad:  # sentinel rows: masked in the router, never sent
-                    fill = np.full(pad, encoding.SENTINEL)
-                    hi = np.concatenate([hi, fill])
-                    lo = np.concatenate([lo, fill])
-                val = np.arange(m + pad, dtype=np.int32)
-                # straight from NumPy: no chunk is staged on device 0
-                args = tuple(jax.device_put(a, sh) for a in (hi, lo, val))
-                # graceful degradation: rare pathological chunks re-run
-                # with a doubled capacity (lossless — overflow is always
-                # detected before anything is dropped)
-                factor = capacity_factor
-                for _ in range(6):
-                    out_val, n_valid, lost = route(*args, factor)
-                    if int(np.asarray(lost).sum()) == 0:
-                        break
-                    stats.fallbacks += 1
-                    factor *= 2.0
-                else:
-                    raise RuntimeError("capacity overflow persisted at 32x")
+                with clock.span("route.encode"):
+                    hi, lo = encoding.encode_np(cb.keys)
+                    # the short final chunk is padded to the full chunk,
+                    # so the route runs one shape
+                    pad = chunk_records - m
+                    if pad:  # sentinel rows: masked in the router
+                        fill = np.full(pad, encoding.SENTINEL)
+                        hi = np.concatenate([hi, fill])
+                        lo = np.concatenate([lo, fill])
+                    val = np.arange(chunk_records, dtype=np.int32)
+                with clock.span("route.exchange"):
+                    # straight from NumPy: no chunk is staged on device 0
+                    args = tuple(
+                        jax.device_put(a, sh) for a in (hi, lo, val)
+                    )
+                    # graceful degradation: rare pathological chunks
+                    # re-run with a doubled capacity (lossless — overflow
+                    # is always detected before anything is dropped)
+                    factor = capacity_factor
+                    for _ in range(6):
+                        out_val, n_valid, lost = route(*args, factor)
+                        clock.add_counter(
+                            "route.exchange_bytes",
+                            route_exchange_bytes(
+                                chunk_records, n_dev,
+                                partition.route_capacity(
+                                    per_device, n_dev, factor
+                                ),
+                            ),
+                        )
+                        if int(np.asarray(lost).sum()) == 0:
+                            break
+                        stats.fallbacks += 1
+                        clock.add_counter("route.retries")
+                        factor *= 2.0
+                    else:
+                        raise RuntimeError(
+                            "capacity overflow persisted at 32x"
+                        )
+                    nv = np.asarray(n_valid).reshape(n_dev)
+                    ov = np.asarray(out_val).reshape(n_dev, -1)
+                clock.add_counter("route.chunks")
                 # spill each device's received range to its range file,
                 # in ascending input order (byte-identical tie handling:
                 # equal keys share a bucket, so input order within a
                 # range is input order globally)
-                nv = np.asarray(n_valid).reshape(n_dev)
-                ov = np.asarray(out_val).reshape(n_dev, -1)
-                for d in range(n_dev):
-                    rows = ov[d, : nv[d]]
-                    rows = np.sort(rows[(rows >= 0) & (rows < m)])
-                    if rows.size == 0:
-                        continue
-                    payload = cb.gather_bytes(rows)
-                    range_files[d].write(payload)
-                    range_counts[d] += int(rows.size)
-                    range_bytes[d] += len(payload)
-                    stats.bytes_written += len(payload)
+                with clock.span("route.spill"):
+                    for d in range(n_dev):
+                        rows = ov[d, : nv[d]]
+                        rows = np.sort(rows[(rows >= 0) & (rows < m)])
+                        if rows.size == 0:
+                            continue
+                        payload = cb.gather_bytes(rows)
+                        range_files[d].write(payload)
+                        range_counts[d] += int(rows.size)
+                        range_bytes[d] += len(payload)
+                        stats.bytes_written += len(payload)
         for f in range_files:
             f.close()
 
@@ -284,13 +312,32 @@ def sort_file_distributed(
     return stats
 
 
+def route_exchange_bytes(rows: int, n_dev: int, capacity: int) -> int:
+    """Bytes that one call of the route program's ``all_to_all`` calls
+    carry, from their operand shapes (each device's own block included):
+    three tiled transposes of the chunk's ``rows`` 4-byte ``hi``, ``lo``
+    and ``val`` words, then every device's ``(n_dev, capacity)`` int32
+    send block of routed row indices.  ``rows`` counts the sentinel pad
+    of the short final chunk."""
+    return 4 * (3 * rows + n_dev * n_dev * capacity)
+
+
 def _make_route_fn(mesh, axis_names, model, n_per_device, capacity_factor):
     """Route-only variant of distributed.make_sort_fn (no device sort —
-    ranges are spilled and sorted once at the end).  Only row indices
-    (``val``) cross the wire; keys are used locally for bucketing and
-    dropped.  Returns ``fn(hi, lo, val) -> (val_routed, n_valid, lost)``
-    with ``val_routed`` per-device arrival-compacted row indices."""
-    from repro.core import partition
+    ranges are spilled and sorted once at the end).  Keys are used
+    locally for bucketing and dropped.  Per call, four ``all_to_all``
+    calls (:func:`route_exchange_bytes`):
+
+    1. three tiled block transposes, of ``hi``, ``lo`` and ``val``: the
+       pre-shuffle, which spreads each device's contiguous input block
+       over every device so that the per-destination counts even out;
+    2. the routed exchange: each device sends a ``(n_dev, capacity)``
+       block of row indices (``-1`` in unused slots) to the owners of
+       their key ranges.
+
+    Returns ``fn(hi, lo, val) -> (val_routed, n_valid, lost)`` with
+    ``val_routed`` per-device arrival-compacted row indices.  The
+    program traces as the module ``jit_route_chunk``."""
     from repro.core.encoding import SENTINEL
 
     axis_names = tuple(axis_names)
@@ -299,7 +346,7 @@ def _make_route_fn(mesh, axis_names, model, n_per_device, capacity_factor):
         n_dev *= mesh.shape[a]
     capacity = partition.route_capacity(n_per_device, n_dev, capacity_factor)
 
-    def local_fn(hi, lo, val):
+    def route_chunk(hi, lo, val):
         def transpose_shuffle(x):
             blk = x.reshape(n_dev, -1)
             return jax.lax.all_to_all(
@@ -334,7 +381,7 @@ def _make_route_fn(mesh, axis_names, model, n_per_device, capacity_factor):
 
     spec = P(axis_names)
     fn = jax.shard_map(
-        local_fn,
+        route_chunk,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec, spec),

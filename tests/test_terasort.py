@@ -9,6 +9,7 @@ mesh) properties — resource cleanup on failure, counter parity with the
 executor, manifest serving — run in-process.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -172,6 +173,59 @@ print("TERASORT2_OK")
 """
 
 
+# The route's spans and counters and the two device programs' names, at
+# 4 devices: a seeded corpus in several chunks and a short tail chunk
+# (sentinel-padded to the full chunk), sorted with the mesh executor.
+# The script prints what the tests below check, as one JSON line.
+SCRIPT_ROUTE = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, tempfile
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import executor, partition, rmi, terasort
+from repro.data import gensort
+from repro.launch.mesh import make_data_mesh
+
+N, CHUNK, N_DEV = 200_003, 1 << 15, 4
+tmp = tempfile.mkdtemp()
+rec = gensort.make_records(N, seed=41)
+inp, out = os.path.join(tmp, "in.bin"), os.path.join(tmp, "out.bin")
+with open(inp, "wb") as f:
+    f.write(rec.tobytes())
+mesh = make_data_mesh(N_DEV)
+stats = terasort.sort_file_distributed(
+    inp, out, mesh, chunk_records=CHUNK, executor="mesh", workdir=tmp
+)
+keys = [rec[i, :10].tobytes() for i in range(N)]
+want = rec[sorted(range(N), key=keys.__getitem__)].tobytes()
+with open(out, "rb") as f:
+    got = f.read()
+
+per_device = CHUNK // N_DEV
+model = rmi.fit(gensort.uniform_keys(4096, seed=5))
+route = terasort._make_route_fn(mesh, ("data",), model, per_device, 1.6)
+u32 = jnp.zeros(CHUNK, jnp.uint32)
+route_hlo = route.lower(u32, u32, jnp.zeros(CHUNK, jnp.int32)).as_text()
+mesh_sort = executor.MeshBatchedExecutor(model, mesh=mesh)._sort_fn(1024)
+sort_hlo = mesh_sort.lower(
+    jnp.zeros((N_DEV, 1024, 8), jnp.uint8),
+    jnp.zeros((N_DEV, 1024), jnp.int32),
+).as_text()
+print(json.dumps({
+    "identical": got == want,
+    "executor": stats.executor,
+    "dispatches": stats.device_dispatches,
+    "fallbacks": stats.fallbacks,
+    "counters": stats.counters,
+    "span_counts": stats.span_counts,
+    "span_seconds": stats.span_seconds,
+    "partition_s": stats.phase_seconds["partition"],
+    "capacity": partition.route_capacity(per_device, N_DEV, 1.6),
+    "route_module": route_hlo.split("module @", 1)[1].split(" ", 1)[0],
+    "sort_module": sort_hlo.split("module @", 1)[1].split(" ", 1)[0],
+}))
+"""
+
 def _run_subprocess(script):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(
@@ -194,6 +248,51 @@ def test_terasort_8dev_mesh_executor_and_formats():
     r = _run_subprocess(SCRIPT2)
     assert r.returncode == 0, r.stdout + "\n" + r.stderr
     assert "TERASORT2_OK" in r.stdout
+
+
+@pytest.fixture(scope="module")
+def route_run():
+    r = _run_subprocess(SCRIPT_ROUTE)
+    assert r.returncode == 0, r.stdout + "\n" + r.stderr
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_terasort_4dev_mesh_executor_matches_stable_sort(route_run):
+    assert route_run["identical"]
+    assert route_run["executor"] == "mesh"
+    assert route_run["dispatches"] >= 1
+
+
+def test_terasort_4dev_one_exchange_span_per_chunk(route_run):
+    chunks = -(-200_003 // (1 << 15))
+    assert route_run["counters"]["route.chunks"] == chunks == 7
+    for span in ("route.encode", "route.exchange", "route.spill"):
+        assert route_run["span_counts"][span] == chunks, span
+    assert route_run["counters"].get("route.retries", 0) == 0
+    assert route_run["fallbacks"] == 0
+
+
+def test_terasort_4dev_exchange_bytes_from_shapes(route_run):
+    """Per chunk: three transposes of its rows' 4-byte words, and 4
+    devices' (4, capacity) int32 send blocks; the tail chunk of
+    200,003 - 6 * 32,768 = 3,395 rows is padded to the full 32,768, so
+    all 7 chunks carry the same bytes."""
+    cap = route_run["capacity"]
+    assert cap == 4096  # next power of two >= 8,192 * 1.6 / 4
+    want = 7 * 4 * (3 * (1 << 15) + 4 * 4 * cap)
+    assert route_run["counters"]["route.exchange_bytes"] == want
+
+
+def test_terasort_4dev_route_spans_within_partition(route_run):
+    spans = route_run["span_seconds"]
+    total = sum(spans[s] for s in
+                ("route.encode", "route.exchange", "route.spill"))
+    assert 0 < total <= route_run["partition_s"]
+
+
+def test_terasort_4dev_route_and_mesh_sort_programs_named_apart(route_run):
+    assert route_run["route_module"] == "jit_route_chunk"
+    assert route_run["sort_module"] == "jit_mesh_flat_sort"
 
 
 # ---------------------------------------------------------------------------
