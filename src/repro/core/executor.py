@@ -113,16 +113,24 @@ class SortExecutor:
                 self.clock.add_counter("jit_compiles")
 
 
-def _memcmp_touchup(keys: np.ndarray, perm: np.ndarray) -> np.ndarray:
+def _memcmp_touchup(
+    keys: np.ndarray, perm: np.ndarray
+) -> tuple[np.ndarray, np.ndarray, int]:
     """Epilogue: fix order beyond the 8-byte embedding (paper's strncmp
-    step, §4) over the full key window, stably."""
+    step, §4) over the full key window, stably.
+
+    Returns ``(perm, keys[perm], key_bytes)``: the keys in the final
+    order (contiguous, ready for :meth:`RecordBlock.take`) and the key
+    bytes gathered to make them — one gather of the strided keys, and
+    one more of the contiguous ones where the order had to change."""
     k = keys[perm]
-    kv = np.ascontiguousarray(k).view(
-        [("k", f"S{k.shape[1]}")]
-    )["k"].reshape(-1)
-    if (kv[:-1] > kv[1:]).any():
-        perm = perm[np.argsort(kv, kind="stable")]
-    return perm
+    w = k.shape[1]
+    kv = k.view([("k", f"S{w}")])["k"].reshape(-1)
+    if not (kv[:-1] > kv[1:]).any():
+        return perm, k, k.nbytes
+    order = np.argsort(kv, kind="stable")
+    k = np.take(k.view(f"V{w}").reshape(-1), order).view(np.uint8)
+    return perm[order], k.reshape(-1, w), 2 * k.nbytes
 
 
 def sort_partition(
@@ -168,8 +176,8 @@ def sort_partition(
         )
         perm = np.asarray(perm)
         perm = perm[perm < m]  # drop sentinel padding
-        perm = _memcmp_touchup(keys, perm)
-        return block.take(perm)
+        perm, keys, _ = _memcmp_touchup(keys, perm)
+        return block.take(perm, keys)
     # host LearnedSort (bucket + radix place + touch-up): no per-partition
     # device dispatch — see learned_sort.sort_host
     perm = learned_sort.sort_host(model, keys)
@@ -238,11 +246,17 @@ class _SuperBatchExecutor(SortExecutor):
         return perm
 
     def _emit(self, tag, block: RecordBlock, local: np.ndarray):
-        """Touch up and gather one segment, then hand it downstream."""
+        """Touch up and gather one segment, then hand it downstream.
+        Counter ``sort.gather_bytes``: the record and key bytes written
+        to build the sorted block."""
         with self._span("sort.touchup"):
-            local = _memcmp_touchup(block.keys, local)
+            local, keys, key_bytes = _memcmp_touchup(block.keys, local)
         with self._span("sort.gather"):
-            block = block.take(local)
+            block = block.take(local, keys)
+        if self.clock is not None:
+            self.clock.add_counter(
+                "sort.gather_bytes", block.n_bytes + key_bytes
+            )
         with self._span("sort.handoff"):
             yield tag, block
 

@@ -144,17 +144,25 @@ class RecordBlock:
         idx = np.repeat(shift, sel) + np.arange(new_off[-1], dtype=np.int64)
         return np.ascontiguousarray(self.data)[idx].tobytes()
 
-    def take(self, perm: np.ndarray) -> "RecordBlock":
-        """Records reordered by ``perm`` (output row i = input row perm[i])."""
+    def take(
+        self, perm: np.ndarray, keys: np.ndarray | None = None
+    ) -> "RecordBlock":
+        """Records reordered by ``perm`` (output row i = input row perm[i]).
+
+        ``keys``, when given, is ``self.keys[perm]`` already gathered (the
+        executor's touch-up has it) and becomes the new block's keys.
+        Fixed-stride blocks copy each record once: one ``np.take`` over
+        the records viewed as ``V{stride}`` items; their offsets are
+        shared, since they do not change."""
         n = self.n_records
+        if keys is None:
+            keys = self.keys[perm]
         lengths = np.diff(self.offsets)
         if n and (lengths == lengths[0]).all():
-            # fixed-stride fast path: one reshape + fancy index
             r = int(lengths[0])
-            data = np.ascontiguousarray(
-                self.data[: n * r].reshape(n, r)[perm]
-            ).reshape(-1)
-            return RecordBlock(data, self.offsets.copy(), self.keys[perm])
+            recs = np.ascontiguousarray(self.data[: n * r]).view(f"V{r}")
+            data = np.take(recs, perm).view(np.uint8)
+            return RecordBlock(data, self.offsets, keys)
         new_len = lengths[perm]
         new_off = np.concatenate(
             [np.zeros(1, np.int64), np.cumsum(new_len, dtype=np.int64)]
@@ -163,9 +171,7 @@ class RecordBlock:
         # dst_start[i]): one vectorized gather over the whole block
         shift = self.offsets[:-1][perm] - new_off[:-1]
         idx = np.repeat(shift, new_len) + np.arange(new_off[-1], dtype=np.int64)
-        return RecordBlock(
-            np.ascontiguousarray(self.data)[idx], new_off, self.keys[perm]
-        )
+        return RecordBlock(np.ascontiguousarray(self.data)[idx], new_off, keys)
 
 
 # ---------------------------------------------------------------------------

@@ -160,7 +160,8 @@ class SortStats:
     Spans nest inside phases, so they stay out of ``total_seconds``.
     ``counters`` holds every ``PhaseClock.add_counter`` total by name
     (``partition.group_bytes``: record bytes the partition grouping
-    wrote).
+    wrote; ``sort.gather_bytes``: record and key bytes the executor's
+    epilogue wrote to build the sorted blocks).
 
     Executor accounting (DESIGN.md §10): ``device_dispatches`` counts
     jitted sort-graph launches, ``batch_occupancy`` is the mean fraction

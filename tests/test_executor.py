@@ -14,10 +14,12 @@ from repro.core import encoding, external, rmi, validate
 from repro.core.executor import (
     BatchedDeviceExecutor,
     HostSortExecutor,
+    MeshBatchedExecutor,
     make_executor,
     sort_partition,
 )
 from repro.core.format import GENSORT, LineFormat
+from repro.core.stages.stats import PhaseClock
 from repro.data import gensort, lines
 from repro.kernels import fused, ref
 
@@ -184,6 +186,50 @@ def test_flat_and_grid_paths_byte_identical(sizes):
         got = dict(ex.sort_iter(enumerate(blocks)))
         outs.append(b"".join(got[i].tobytes() for i in range(len(sizes))))
     assert outs[0] == outs[1]
+
+
+def _prefix_tied_blocks(sizes, seed=0):
+    """Gensort blocks whose keys come in groups of about six that share
+    their first 8 bytes and differ in bytes 8-9: the device orders the
+    8-byte prefix only, so the touch-up has to reorder every segment."""
+    total = sum(sizes)
+    recs = gensort.make_records(total, seed=seed)
+    recs[:, :8] = recs[np.arange(total) % (total // 6), :8]
+    ends = np.cumsum(sizes)
+    return [GENSORT.parse_blob(recs[e - m : e].tobytes())
+            for m, e in zip(sizes, ends)]
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("kind", ["batched", "mesh"])
+def test_batching_executors_emit_the_keys_of_their_records(kind, ties):
+    """Each emitted block's keys are the touch-up's, in the final order:
+    key i is the first 10 bytes of record i, also where prefix ties make
+    the touch-up reorder.  The epilogue writes each record once and
+    gathers each key once, twice where the order changed
+    (``sort.gather_bytes``)."""
+    from repro.launch.mesh import make_mesh
+
+    model = _model()
+    sizes = [1500, 2500]
+    blocks = (_prefix_tied_blocks(sizes, seed=12) if ties
+              else _blocks(sizes, seed=11))
+    clock = PhaseClock()
+    if kind == "mesh":
+        mesh = make_mesh((1,), ("data",))
+        ex = MeshBatchedExecutor(model, mesh=mesh, clock=clock)
+    else:
+        ex = BatchedDeviceExecutor(model, clock=clock)
+    got = dict(ex.sort_iter(enumerate(blocks)))
+    for i, blk in enumerate(blocks):
+        out = got[i]
+        assert out.tobytes() == _host_sorted(model, blk).tobytes()
+        recs = np.frombuffer(out.tobytes(), np.uint8).reshape(-1, 100)
+        np.testing.assert_array_equal(out.keys, recs[:, :10])
+    input_bytes = sum(b.n_bytes for b in blocks)
+    # 100-byte records, 10-byte keys: 1.1 the input, 1.2 with a reorder
+    want = input_bytes * (12 if ties else 11) // 10
+    assert clock.counters["sort.gather_bytes"] == want
 
 
 def test_pad_target_waste_bounded():

@@ -90,6 +90,43 @@ def test_line_take_permutation(corpus):
     assert took.n_bytes == block.n_bytes
 
 
+def _fixed_block(source: str, n: int, tmp_path):
+    """An ``n``-record gensort block: owned, mmap-backed, or a
+    ``slice_records`` view that starts 3 records into its parent."""
+    fmt = FixedFormat(100, 10)
+    if source == "owned":
+        return fmt.parse_blob(gensort.make_records(n, seed=5).tobytes())
+    if source == "mmap":
+        p = str(tmp_path / "fixed.bin")
+        gensort.make_records(n, seed=5).tofile(p)
+        return fmt.read_block(p)
+    parent = fmt.parse_blob(gensort.make_records(n + 5, seed=5).tobytes())
+    return parent.slice_records(3, 3 + n)
+
+
+@pytest.mark.parametrize("pass_keys", [False, True])
+@pytest.mark.parametrize("n", [0, 1, 2, 57])
+@pytest.mark.parametrize("source", ["owned", "mmap", "slice"])
+def test_fixed_take_matches_row_by_row(source, n, pass_keys, tmp_path):
+    """take(perm) on fixed-stride records: record i of the result is
+    record perm[i], its key the record's first 10 bytes, whether the
+    keys are the block's strided view gathered by take or passed in
+    already gathered; the result owns its bytes."""
+    block = _fixed_block(source, n, tmp_path)
+    assert block.n_records == n
+    perm = np.random.default_rng(n).permutation(n)
+    keys = np.ascontiguousarray(block.keys[perm]) if pass_keys else None
+    took = block.take(perm, keys)
+    assert took.n_records == n and took.n_bytes == 100 * n
+    assert type(took.data) is np.ndarray and took.data.dtype == np.uint8
+    assert not np.shares_memory(took.data, block.data)
+    assert took.tobytes() == b"".join(block.record(int(j)) for j in perm)
+    assert took.keys.shape == (n, 10)
+    for i, j in enumerate(perm):
+        assert bytes(took.keys[i]) == block.record(int(j))[:10]
+        assert bytes(took.keys[i]) == took.record(i)[:10]
+
+
 # ---------------------------------------------------------------------------
 # Delimiter-boundary fragment splits
 # ---------------------------------------------------------------------------
